@@ -13,10 +13,11 @@ On the 100-user synthetic fleet (the PR 2 store-bench config), both tasks:
   > 1.0 means the engine overlapped them.  Under interpret mode (CPU) the
   DMA pipeline is emulated serially, so this hovers near 1.0 — the number
   exists to track REAL overlap once the kernel runs on TPU hardware;
-* single- vs multi-device scaling: sharded warm rows/s at 1/2/4 devices
-  (re-executed subprocesses with ``--xla_force_host_platform_device_count``;
-  forced host devices share the same physical cores, so CPU numbers
-  validate the mechanism, not a speedup);
+* single- vs multi-device scaling: sharded warm rows/s over the first
+  1/2/4 devices, in one process (on the CPU, run with
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=4``; forced host
+  devices share the same physical cores, so CPU numbers validate the
+  mechanism, not a speedup);
 * parity: every engine's predictions vs per-user ``predict_compressed`` —
   classification must be bit-exact, regression reports the float32
   accumulation max error.
@@ -29,10 +30,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -191,10 +189,15 @@ def bench_fleet(task, n_users, n_requests, rows_per_request, repeats,
     }
 
 
-def worker_main(args) -> None:
-    """Subprocess entry (one fixed device count): sharded warm rows/s."""
+def device_scaling(args, device_counts):
+    """Sharded warm rows/s over the first k of ``jax.devices()`` for each
+    k, in THIS process (one process owns the devices).  On the CPU the
+    device count comes from ``XLA_FLAGS=
+    --xla_force_host_platform_device_count=N`` set on the command line;
+    counts above ``len(jax.devices())`` are skipped."""
     import jax
 
+    from repro.serving import ForestServer
     from repro.store import (
         build_store,
         make_request_batch,
@@ -205,36 +208,21 @@ def worker_main(args) -> None:
                                  seed=0)
     store = build_store(fleet)
     requests = make_request_batch(store, args.requests, args.rows, 1)
-    t_warm, _ = time_engine(store, requests, "sharded", args.repeats)
     n_rows = sum(len(x) for _, x in requests)
-    print(json.dumps({
-        # the ACTUAL device count, so a stray inherited XLA flag that
-        # overrode the request cannot mislabel the scaling table
-        "devices": len(jax.devices()),
-        "sharded_warm_ms": round(t_warm * 1e3, 2),
-        "sharded_rows_per_s": round(n_rows / t_warm, 1),
-    }))
-
-
-def device_scaling(args, device_counts):
-    """Re-exec this script per device count (the XLA host-device count is
-    fixed at process start) and collect the sharded engine's warm rows/s."""
     rows = []
     for n_dev in device_counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (  # XLA flag parsing is last-wins: append OUR
-            env.get("XLA_FLAGS", "")  # override after any inherited flags
-            + f" --xla_force_host_platform_device_count={n_dev}"
-        ).strip()
-        cmd = [
-            sys.executable, __file__, "--_worker-devices", str(n_dev),
-            "--users", str(args.users), "--requests", str(args.requests),
-            "--rows", str(args.rows), "--repeats", str(args.repeats),
-        ]
-        out = subprocess.run(
-            cmd, env=env, capture_output=True, text=True, check=True
+        if n_dev > len(jax.devices()):
+            continue
+        server = ForestServer(store, n_devices=n_dev)
+        server.serve(requests, engine="sharded")  # compile + warm
+        t_warm, _ = best_of(
+            lambda: server.serve(requests, engine="sharded"), args.repeats
         )
-        rows.append(json.loads(out.stdout.strip().splitlines()[-1]))
+        rows.append({
+            "devices": n_dev,
+            "sharded_warm_ms": round(t_warm * 1e3, 2),
+            "sharded_rows_per_s": round(n_rows / t_warm, 1),
+        })
     return rows
 
 
@@ -247,12 +235,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--rows", type=int, default=128)
     ap.add_argument("--repeats", type=int, default=5)
-    ap.add_argument("--_worker-devices", type=int, default=None,
-                    dest="worker_devices", help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if args.worker_devices is not None:
-        worker_main(args)
-        return
     if args.quick:
         args.users, args.requests, args.rows, args.repeats = 8, 6, 32, 2
     out_path = pathlib.Path(

@@ -24,8 +24,11 @@ as a differential oracle and as the benchmark baseline
 """
 from __future__ import annotations
 
+import functools
 from typing import Iterator
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .bitio import BitReader
@@ -233,70 +236,40 @@ def stacked_forest(comp: CompressedForest) -> StackedForest:
     return cached
 
 
-_jax_traverse = None  # resolved lazily; False => jax unavailable
+@functools.partial(jax.jit, static_argnames=("depth",))
+def _traverse(feat, thr, lft, rgt, fit, xb, depth):
+    """(T, N) leaf ``node_fit`` of every tree for every row, walking all
+    trees one level at a time with integer gathers."""
+    nn = xb.shape[0]
+    xb_t = xb.T
+    cols = jnp.arange(nn)[None, :]
+    idx = jnp.zeros((feat.shape[0], nn), jnp.int32)
 
+    def level(_, idx):
+        fe = jnp.take_along_axis(feat, idx, axis=1)
+        xv = xb_t[fe, cols]
+        go_left = xv <= jnp.take_along_axis(thr, idx, axis=1)
+        return jnp.where(
+            go_left,
+            jnp.take_along_axis(lft, idx, axis=1),
+            jnp.take_along_axis(rgt, idx, axis=1),
+        )
 
-def _get_jax_traverse():
-    global _jax_traverse
-    if _jax_traverse is None:
-        try:
-            import functools
-
-            import jax
-            import jax.numpy as jnp
-
-            @functools.partial(jax.jit, static_argnames=("depth",))
-            def traverse(feat, thr, lft, rgt, fit, xb, depth):
-                nn = xb.shape[0]
-                xb_t = xb.T
-                cols = jnp.arange(nn)[None, :]
-                idx = jnp.zeros((feat.shape[0], nn), jnp.int32)
-
-                def level(_, idx):
-                    fe = jnp.take_along_axis(feat, idx, axis=1)
-                    xv = xb_t[fe, cols]
-                    go_left = xv <= jnp.take_along_axis(thr, idx, axis=1)
-                    return jnp.where(
-                        go_left,
-                        jnp.take_along_axis(lft, idx, axis=1),
-                        jnp.take_along_axis(rgt, idx, axis=1),
-                    )
-
-                idx = jax.lax.fori_loop(0, depth, level, idx)
-                return jnp.take_along_axis(fit, idx, axis=1)
-
-            _jax_traverse = traverse
-        except Exception:  # pragma: no cover - jax is a baked-in dependency
-            _jax_traverse = False
-    return _jax_traverse or None
+    idx = jax.lax.fori_loop(0, depth, level, idx)
+    return jnp.take_along_axis(fit, idx, axis=1)
 
 
 def _batched_leaf_fits(sf: StackedForest, x_binned: np.ndarray) -> np.ndarray:
     """(T, N) leaf ``node_fit`` per (tree, observation): one traversal over
     ALL trees at once — the level loop runs max-depth times, not
     n_trees * depth times.  Routing is all-integer, so the result is
-    bit-exact regardless of backend (jitted XLA when jax is importable,
-    numpy gathers otherwise)."""
+    bit-exact on every backend."""
     x_binned = np.ascontiguousarray(x_binned, dtype=np.int32)
-    traverse = _get_jax_traverse()
-    if traverse is not None:
-        out = traverse(
-            sf.feature, sf.threshold, sf.left, sf.right, sf.fit,
-            x_binned, depth=sf.max_depth,
-        )
-        return np.asarray(out)
-    xb_t = np.ascontiguousarray(x_binned.T)
-    cols = np.arange(x_binned.shape[0])[None, :]
-    idx = np.zeros((sf.feature.shape[0], x_binned.shape[0]), dtype=np.int32)
-    for _ in range(sf.max_depth):
-        fe = np.take_along_axis(sf.feature, idx, axis=1)
-        go_left = xb_t[fe, cols] <= np.take_along_axis(sf.threshold, idx, axis=1)
-        idx = np.where(
-            go_left,
-            np.take_along_axis(sf.left, idx, axis=1),
-            np.take_along_axis(sf.right, idx, axis=1),
-        )
-    return np.take_along_axis(sf.fit, idx, axis=1)
+    out = _traverse(
+        sf.feature, sf.threshold, sf.left, sf.right, sf.fit,
+        x_binned, depth=sf.max_depth,
+    )
+    return np.asarray(out)
 
 
 def predict_compressed(

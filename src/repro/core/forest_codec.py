@@ -326,6 +326,7 @@ def _build_component(
     coder: str,
     k_max: int,
     seed: int,
+    engine: str = "auto",
 ) -> tuple[ClusteredComponent, ClusteringResult]:
     """Cluster the models and build per-cluster codebooks.
 
@@ -337,7 +338,9 @@ def _build_component(
     if len(used) == 0:
         comp = ClusteredComponent(full_map, [], [], [], coder, [])
         return comp, ClusteringResult(np.zeros(0, int), np.zeros((0, 0)), 0, 0, 0, 0)
-    res = cluster_models(counts[used], alpha_bits, k_max=k_max, seed=seed)
+    res = cluster_models(
+        counts[used], alpha_bits, k_max=k_max, seed=seed, engine=engine
+    )
     # compact cluster ids to 0..K-1
     uniq, compact = np.unique(res.assignments, return_inverse=True)
     full_map[used] = compact.astype(np.int16)
@@ -356,8 +359,11 @@ def _build_component(
 
 
 def compress_forest(
-    forest: Forest, k_max: int = 12, seed: int = 0
+    forest: Forest, k_max: int = 12, seed: int = 0, engine: str = "auto"
 ) -> CompressedForest:
+    """``engine`` is the Bregman clustering engine (``core.bregman``):
+    ``"chunked"`` runs in numpy and compiles nothing, where ``"dense"``
+    compiles one program per model-set shape."""
     meta = forest.meta
     d = meta.n_features
     rec = extract_records(forest)
@@ -374,7 +380,7 @@ def compress_forest(
     # ---- 2. variable names -----------------------------------------------
     v_counts = var_name_counts(rec, d, t_max)
     vars_comp, _ = _build_component(
-        v_counts, alpha_vars(d), "huffman", k_max, seed
+        v_counts, alpha_vars(d), "huffman", k_max, seed, engine
     )
 
     # ---- 3. split values (per variable) ----------------------------------
@@ -386,7 +392,9 @@ def compress_forest(
             meta.n_train_obs,
             int(meta.n_bins_per_feature[v]),
         )
-        splits_comp[v], _ = _build_component(cnts, a, "huffman", k_max, seed)
+        splits_comp[v], _ = _build_component(
+            cnts, a, "huffman", k_max, seed, engine
+        )
 
     # ---- 4. fits -----------------------------------------------------------
     if meta.task == "classification":
@@ -402,7 +410,8 @@ def compress_forest(
         fits_coder = "huffman"
     f_counts = fit_counts(rec, d, t_max, n_fit_syms)
     fits_comp, _ = _build_component(
-        f_counts, alpha_fits(meta.task, n_fit_syms), fits_coder, k_max, seed
+        f_counts, alpha_fits(meta.task, n_fit_syms), fits_coder, k_max,
+        seed, engine,
     )
 
     # ---- 5. emit streams in global preorder --------------------------------

@@ -106,19 +106,24 @@ def estimate_shard_speedup(seg_trees: np.ndarray, n_shards: int) -> float:
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_callable(
-    n_devices: int, max_depth: int, n_classes: int, block_trees: int,
+def shard_mesh(n_devices: int):
+    """The 1-D ``("shard",)`` mesh over the first ``n_devices`` devices
+    that the sharded engine's tree shards are placed on and run over."""
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:n_devices]), ("shard",))
+
+
+def _sharded_program(
+    mesh, max_depth: int, n_classes: int, block_trees: int,
     block_obs: int, tb2: int, interpret: bool,
 ):
-    """Build (once per static config) the jitted shard_map program: each
-    device runs the pipelined segmented kernel on ITS tree shard against
-    the full replicated batch, then the (N, C) partials all-reduce."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
+    """The shard_map program over ``mesh``: each device runs the pipelined
+    segmented kernel on ITS tree shard against the full replicated batch,
+    then the (N, C) partials all-reduce."""
+    from jax.sharding import PartitionSpec as P
 
     from .tree_predict import _forest_predict_agg_seg_pipelined_impl
-
-    mesh = Mesh(np.array(jax.devices()[:n_devices]), ("shard",))
 
     def per_device(xb, oseg, code, fit, tseg, chunk_lo, chunk_hi):
         part = _forest_predict_agg_seg_pipelined_impl(
@@ -129,7 +134,7 @@ def _sharded_callable(
             part = part[:, None]
         return jax.lax.psum(part, "shard")
 
-    fn = shard_map(
+    return jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(
@@ -137,9 +142,15 @@ def _sharded_callable(
             P("shard"),
         ),
         out_specs=P(),
-        check_rep=False,  # pallas_call has no replication rule
+        check_vma=False,  # pallas_call has no replication rule
     )
-    return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_callable(n_devices: int, *static):
+    """The jitted ``_sharded_program`` over ``shard_mesh(n_devices)``, built
+    once per static config."""
+    return jax.jit(_sharded_program(shard_mesh(n_devices), *static))
 
 
 def forest_predict_agg_segmented_sharded(

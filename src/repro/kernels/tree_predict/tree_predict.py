@@ -9,12 +9,13 @@ select ops + MXU one-hot contractions.  Trees are tiny and reused across the
 whole observation tile, so the kernel is gather-throughput-bound in VMEM
 rather than HBM-bound: per HBM byte of tree data we do BN gathers.
 
-Gathers use TWO-LEVEL one-hot contractions: a heap index over ``Hp`` nodes is
-split into (hi, lo) = (idx >> lo_bits, idx & (Hlo - 1)) and gathered as
-``sum_l one_hot(hi) @ tab[:, hi, :] * one_hot(lo)``.  The one-hot operands
-are (BT, BN, Hhi) + (BT, BN, Hlo) ~ O(sqrt(H)) per element instead of the
-(BT, BN, H) materialization of a flat one-hot — the VMEM working set stays
-flat as depth grows (depth 14 => 180x smaller level scratch).
+Gathers are TWO-LEVEL: a heap index over ``Hp`` nodes is split into
+(hi, lo) = (idx >> 7, idx & 127); a one-hot MXU contraction over ``hi``
+picks each pair's 128-lane row of the table, and a lane select-and-sum
+picks ``lo`` from it.  The working set is (BT, BN, Hp / 128) + (BT, BN,
+128) per element instead of the (BT, BN, Hp) of a flat one-hot.  ``lo``
+spans exactly one lane row because Mosaic cannot split the lane dim
+below 128.
 
 Three kernels share the traversal:
 
@@ -32,8 +33,8 @@ Three kernels share the traversal:
 
 The segmented kernel comes in TWO engines (``engine=`` on the wrapper):
 
-* ``"simple"``  — the original grid-per-tree-tile kernel, kept verbatim as
-  the differential oracle and the PR 2 serving baseline;
+* ``"simple"``  — the original grid-per-tree-tile kernel, kept as the
+  differential oracle and the PR 2 serving baseline;
 * ``"pipelined"`` (default when inputs allow) — one launch per batch with a
   MANUAL double-buffered DMA pipeline: tree tiles live in HBM
   (``memory_space=ANY``) and the kernel streams them into two VMEM slots
@@ -50,15 +51,17 @@ The segmented kernel comes in TWO engines (``engine=`` on the wrapper):
     otherwise.
   - **block-diagonal chunk skipping**: per observation block the wrapper
     precomputes (host side) the [lo, hi) range of tree chunks whose
-    segment set intersects the block's, shipped via SMEM; with rows and
+    segment set intersects the block's, shipped via SMEM with the trees'
+    segment ids; with rows and
     trees sorted by segment the kernel touches ~sum_u T_u * N_u work, not
     T_total * N_total, in ONE launch with no host round-trips between
     chunks.
 
-Precision guard: node attributes round-trip through float32 one-hot einsums,
-which is exact only below 2**24 — ``forest_predict*`` validate static shapes
-and (when inputs are concrete) data ranges and raise instead of silently
-corrupting (see tests/test_serve_path.py boundary test).
+Precision guard: node attributes round-trip through float32 gathers (the
+one-hot contraction runs at HIGHEST precision), which are exact only
+below 2**24 — ``forest_predict*`` validate static shapes and (when inputs
+are concrete) data ranges and raise instead of silently corrupting (see
+tests/test_serve_path.py boundary test).
 """
 from __future__ import annotations
 
@@ -74,8 +77,10 @@ _F32_EXACT_INT = 1 << 24  # float32 has a 24-bit significand
 
 
 def _validate_f32_exact(max_depth: int, d: int, **arrays) -> None:
-    """Raise if a value routed through the float32 one-hot path could exceed
-    the exactly-representable integer range.
+    """Raise if a value routed through the float32 gathers (node fields,
+    fused code words, binned features) could exceed the exactly-representable
+    integer range.  Node ids stay int32 in the kernels; heaps of 2**24 nodes
+    or more are refused as well, their tables being far past any VMEM.
 
     Host numpy arrays are checked with numpy (free); concrete device arrays
     are checked too, which costs a device sync — hot loops (the streamed
@@ -84,8 +89,8 @@ def _validate_f32_exact(max_depth: int, d: int, **arrays) -> None:
     h = (1 << (max_depth + 1)) - 1
     if h >= _F32_EXACT_INT:
         raise ValueError(
-            f"max_depth={max_depth} gives {h} heap nodes >= 2**24; node ids "
-            "would corrupt in the float32 one-hot gathers"
+            f"max_depth={max_depth} gives {h} heap nodes >= 2**24; the "
+            "kernels' heap tables cannot hold that many"
         )
     if d >= _F32_EXACT_INT:
         raise ValueError(f"n_features={d} >= 2**24 overflows float32 gathers")
@@ -105,12 +110,17 @@ def _validate_f32_exact(max_depth: int, d: int, **arrays) -> None:
             )
 
 
-def _heap_split(h_pad: int) -> tuple[int, int, int]:
-    """(lo_bits, n_lo, n_hi) for the two-level gather over h_pad heap slots."""
-    lo_bits = max(1, h_pad.bit_length() // 2)
-    n_lo = 1 << lo_bits
-    n_hi = pl.cdiv(h_pad, n_lo)
-    return lo_bits, n_lo, n_hi
+#: low index bits of the two-level gather: one 128-lane vreg row per ``hi``
+#: value, so the heap reshape ``(BT, H) -> (BT, n_hi, 128)`` never splits a
+#: lane row (Mosaic refuses shape casts that split the lane dim below 128)
+LO_BITS = 7
+N_LO = 1 << LO_BITS
+
+
+def _heap_split(h: int) -> int:
+    """``n_hi`` for the two-level gather over ``h`` heap slots; the padded
+    heap width is ``n_hi * N_LO``."""
+    return max(pl.cdiv(h, N_LO), 1)
 
 
 def _pad_heap(a: jnp.ndarray, h_pad: int) -> jnp.ndarray:
@@ -120,87 +130,132 @@ def _pad_heap(a: jnp.ndarray, h_pad: int) -> jnp.ndarray:
     return jnp.pad(a, ((0, 0), (0, h_pad - h)))
 
 
-def _two_level_gather(tab3, oh_hi, oh_lo):
-    """tab3 (BT, Hhi, Hlo) f32, oh_hi (BT, BN, Hhi), oh_lo (BT, BN, Hlo)
-    -> (BT, BN) gathered values."""
-    rows = jnp.einsum(
-        "tnh,thl->tnl", oh_hi, tab3, preferred_element_type=jnp.float32
-    )
-    return (rows * oh_lo).sum(-1)
+def _two_level_gather(tab3, idx):
+    """``tab3[t, idx[t, n]]`` for tab3 (BT, n_hi, 128) f32 and idx (BT, BN)
+    int32 -> (BT, BN) f32.
+
+    The ``hi`` half is a one-hot MXU contraction at HIGHEST precision (the
+    default rounds f32 operands to bf16, which would corrupt code words
+    above 2**8); the ``lo`` half is a lane select-and-sum.  Each output
+    sums exactly one nonzero term, so values below 2**24 come back
+    exact."""
+    n_hi = tab3.shape[1]
+    if n_hi == 1:
+        rows = jnp.broadcast_to(
+            tab3, (tab3.shape[0], idx.shape[1], N_LO)
+        )
+    else:
+        oh_hi = jax.nn.one_hot(idx >> LO_BITS, n_hi, dtype=jnp.float32)
+        rows = jnp.einsum(
+            "tnh,thl->tnl", oh_hi, tab3,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+    lanes = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 2)
+    hit = lanes == (idx & (N_LO - 1))[..., None]
+    return jnp.where(hit, rows, 0.0).sum(-1)
 
 
-def _traverse(xb, feat, thr, inter, *, max_depth, lo_bits, n_lo, n_hi, d):
-    """Shared (BT, BN) heap traversal; returns final node indices."""
-    bt = feat.shape[0]
-    bn = xb.shape[0]
-    feat3 = feat.astype(jnp.float32).reshape(bt, n_hi, n_lo)
-    thr3 = thr.astype(jnp.float32).reshape(bt, n_hi, n_lo)
-    inter3 = inter.astype(jnp.float32).reshape(bt, n_hi, n_lo)
+def _read_feature(fe, xbf):
+    """``xbf[n, fe[t, n]]`` for fe (BT, BN) int32 and xbf (BN, d) f32 ->
+    (BT, BN) f32: a lane select-and-sum (a batched mat-vec has no
+    non-contracting lhs dim, which Mosaic cannot lower)."""
+    d = xbf.shape[-1]
+    bt, bn = fe.shape
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (bt, bn, d), 2)
+    hit = lanes == jnp.clip(fe, 0, d - 1)[..., None]
+    return jnp.where(hit, xbf[None], 0.0).sum(-1)
+
+
+def _traverse(xb, node_at, *, max_depth, bt):
+    """Shared (BT, BN) heap traversal; returns final node indices.
+    ``node_at(idx)`` gathers the (feature int32, threshold f32, internal
+    bool) fields of nodes ``idx``."""
     xbf = xb.astype(jnp.float32)
-    idx = jnp.zeros((bt, bn), jnp.int32)
 
     def level(_, idx):
-        oh_hi = jax.nn.one_hot(idx >> lo_bits, n_hi, dtype=jnp.float32)
-        oh_lo = jax.nn.one_hot(idx & (n_lo - 1), n_lo, dtype=jnp.float32)
-        fe = _two_level_gather(feat3, oh_hi, oh_lo).astype(jnp.int32)
-        th = _two_level_gather(thr3, oh_hi, oh_lo).astype(jnp.int32)
-        it = _two_level_gather(inter3, oh_hi, oh_lo) > 0.5
-        ohf = jax.nn.one_hot(jnp.clip(fe, 0, d - 1), d, dtype=jnp.float32)
-        xv = jnp.einsum(
-            "tnd,nd->tn", ohf, xbf, preferred_element_type=jnp.float32
-        ).astype(jnp.int32)
-        child = jnp.where(xv <= th, 2 * idx + 1, 2 * idx + 2)
-        return jnp.where(it, child, idx)
+        fe, th, internal = node_at(idx)
+        child = jnp.where(
+            _read_feature(fe, xbf) <= th, 2 * idx + 1, 2 * idx + 2
+        )
+        return jnp.where(internal, child, idx)
 
+    idx = jnp.zeros((bt, xb.shape[0]), jnp.int32)
     return jax.lax.fori_loop(0, max_depth, level, idx)
+
+
+def _split_fields(feat, thr, inter, n_hi):
+    """node_at over three separate (BT, H_pad) attribute tables."""
+    bt = feat.shape[0]
+    feat3, thr3, inter3 = (
+        a.astype(jnp.float32).reshape(bt, n_hi, N_LO)
+        for a in (feat, thr, inter)
+    )
+
+    def node_at(idx):
+        return (
+            _two_level_gather(feat3, idx).astype(jnp.int32),
+            _two_level_gather(thr3, idx),
+            _two_level_gather(inter3, idx) > 0.5,
+        )
+
+    return node_at
+
+
+def _aggregate(leaf, valid, n_classes):
+    """(BT, BN) leaf fits + (BT, BN) bool mask -> lane-dense (C, BN) vote
+    counts (classification) or (1, BN) fit sums (regression)."""
+    if n_classes == 0:
+        return jnp.where(valid, leaf, 0.0).sum(0, keepdims=True)
+    # one 2-D sublane reduction per class, placed into its output row (a
+    # 3-D (C, BT, BN) reduction has no Mosaic lowering)
+    cls = jnp.where(valid, leaf.astype(jnp.int32), -1)
+    out_row = jax.lax.broadcasted_iota(
+        jnp.int32, (n_classes, leaf.shape[1]), 0
+    )
+    votes = jnp.zeros(out_row.shape, jnp.float32)
+    for c in range(n_classes):
+        count = (cls == c).astype(jnp.float32).sum(0, keepdims=True)
+        votes = jnp.where(out_row == c, count, votes)
+    return votes
 
 
 def _tree_predict_kernel(
     xb_ref, feat_ref, thr_ref, fit_ref, inter_ref, out_ref,
-    *, max_depth: int, lo_bits: int, n_lo: int, n_hi: int, d: int,
+    *, max_depth: int, n_hi: int,
 ):
-    idx = _traverse(
-        xb_ref[...], feat_ref[...], thr_ref[...], inter_ref[...],
-        max_depth=max_depth, lo_bits=lo_bits, n_lo=n_lo, n_hi=n_hi, d=d,
-    )
     bt = fit_ref.shape[0]
-    fit3 = fit_ref[...].reshape(bt, n_hi, n_lo)
-    oh_hi = jax.nn.one_hot(idx >> lo_bits, n_hi, dtype=jnp.float32)
-    oh_lo = jax.nn.one_hot(idx & (n_lo - 1), n_lo, dtype=jnp.float32)
-    out_ref[...] = _two_level_gather(fit3, oh_hi, oh_lo)
+    idx = _traverse(
+        xb_ref[...],
+        _split_fields(feat_ref[...], thr_ref[...], inter_ref[...], n_hi),
+        max_depth=max_depth, bt=bt,
+    )
+    fit3 = fit_ref[...].reshape(bt, n_hi, N_LO)
+    out_ref[...] = _two_level_gather(fit3, idx)
 
 
 def _tree_predict_agg_kernel(
     xb_ref, feat_ref, thr_ref, fit_ref, inter_ref, out_ref,
-    *, max_depth: int, lo_bits: int, n_lo: int, n_hi: int, d: int,
-    n_classes: int, block_trees: int, n_trees: int,
+    *, max_depth: int, n_hi: int, n_classes: int, block_trees: int,
+    n_trees: int,
 ):
+    bt = fit_ref.shape[0]
     idx = _traverse(
-        xb_ref[...], feat_ref[...], thr_ref[...], inter_ref[...],
-        max_depth=max_depth, lo_bits=lo_bits, n_lo=n_lo, n_hi=n_hi, d=d,
+        xb_ref[...],
+        _split_fields(feat_ref[...], thr_ref[...], inter_ref[...], n_hi),
+        max_depth=max_depth, bt=bt,
     )
-    bt, bn = idx.shape
-    fit3 = fit_ref[...].reshape(bt, n_hi, n_lo)
-    oh_hi = jax.nn.one_hot(idx >> lo_bits, n_hi, dtype=jnp.float32)
-    oh_lo = jax.nn.one_hot(idx & (n_lo - 1), n_lo, dtype=jnp.float32)
-    leaf = _two_level_gather(fit3, oh_hi, oh_lo)  # (BT, BN)
+    leaf = _two_level_gather(fit_ref[...].reshape(bt, n_hi, N_LO), idx)
     # mask trees past T (grid padding): their tile rows hold garbage
     j = pl.program_id(1)
-    tree_ids = jax.lax.broadcasted_iota(jnp.int32, (bt, bn), 0)
-    valid = (tree_ids + j * block_trees < n_trees).astype(jnp.float32)
-    if n_classes > 0:
-        oh_c = jax.nn.one_hot(
-            leaf.astype(jnp.int32), n_classes, dtype=jnp.float32
-        )
-        contrib = (oh_c * valid[..., None]).sum(0)  # (BN, C) vote counts
-    else:
-        contrib = (leaf * valid).sum(0)[:, None]  # (BN, 1) fit sum
+    tree_ids = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
+    valid = tree_ids + j * block_trees < n_trees
 
     @pl.when(j == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += contrib
+    out_ref[...] += _aggregate(leaf, valid, n_classes)
 
 
 @functools.partial(
@@ -213,16 +268,15 @@ def _forest_predict_impl(
 ):
     t, h = feature.shape
     n, d = xb.shape
-    lo_bits, n_lo, n_hi = _heap_split(h)
-    h_pad = n_lo * n_hi
+    n_hi = _heap_split(h)
+    h_pad = n_hi * N_LO
     feature, threshold, fit, inter = (
         _pad_heap(a, h_pad)
         for a in (feature, threshold, fit, is_internal.astype(jnp.int32))
     )
     grid = (pl.cdiv(t, block_trees), pl.cdiv(n, block_obs))
     kernel = functools.partial(
-        _tree_predict_kernel,
-        max_depth=max_depth, lo_bits=lo_bits, n_lo=n_lo, n_hi=n_hi, d=d,
+        _tree_predict_kernel, max_depth=max_depth, n_hi=n_hi,
     )
     tree_spec = lambda: pl.BlockSpec((block_trees, h_pad), lambda i, j: (i, 0))
     return pl.pallas_call(
@@ -268,38 +322,28 @@ def forest_predict(
 def _tree_predict_agg_seg_kernel(
     xb_ref, oseg_ref, tseg_ref, feat_ref, thr_ref, fit_ref, inter_ref,
     out_ref,
-    *, max_depth: int, lo_bits: int, n_lo: int, n_hi: int, d: int,
-    n_classes: int, block_trees: int, n_trees: int,
+    *, max_depth: int, n_hi: int, n_classes: int, block_trees: int,
+    n_trees: int,
 ):
+    bt = fit_ref.shape[0]
     idx = _traverse(
-        xb_ref[...], feat_ref[...], thr_ref[...], inter_ref[...],
-        max_depth=max_depth, lo_bits=lo_bits, n_lo=n_lo, n_hi=n_hi, d=d,
+        xb_ref[...],
+        _split_fields(feat_ref[...], thr_ref[...], inter_ref[...], n_hi),
+        max_depth=max_depth, bt=bt,
     )
-    bt, bn = idx.shape
-    fit3 = fit_ref[...].reshape(bt, n_hi, n_lo)
-    oh_hi = jax.nn.one_hot(idx >> lo_bits, n_hi, dtype=jnp.float32)
-    oh_lo = jax.nn.one_hot(idx & (n_lo - 1), n_lo, dtype=jnp.float32)
-    leaf = _two_level_gather(fit3, oh_hi, oh_lo)  # (BT, BN)
+    leaf = _two_level_gather(fit_ref[...].reshape(bt, n_hi, N_LO), idx)
     # a (tree, obs) pair contributes iff the tree is real (grid padding) AND
     # its segment (user) id matches the observation's segment id
     j = pl.program_id(1)
-    tree_ids = jax.lax.broadcasted_iota(jnp.int32, (bt, bn), 0)
+    tree_ids = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
     in_range = tree_ids + j * block_trees < n_trees
     same_seg = tseg_ref[...] == oseg_ref[...]  # (BT,1) vs (1,BN) -> (BT,BN)
-    valid = (in_range & same_seg).astype(jnp.float32)
-    if n_classes > 0:
-        oh_c = jax.nn.one_hot(
-            leaf.astype(jnp.int32), n_classes, dtype=jnp.float32
-        )
-        contrib = (oh_c * valid[..., None]).sum(0)  # (BN, C) vote counts
-    else:
-        contrib = (leaf * valid).sum(0)[:, None]  # (BN, 1) fit sum
 
     @pl.when(j == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += contrib
+    out_ref[...] += _aggregate(leaf, in_range & same_seg, n_classes)
 
 
 @functools.partial(
@@ -314,8 +358,8 @@ def _forest_predict_agg_seg_impl(
 ):
     t, h = feature.shape
     n, d = xb.shape
-    lo_bits, n_lo, n_hi = _heap_split(h)
-    h_pad = n_lo * n_hi
+    n_hi = _heap_split(h)
+    h_pad = n_hi * N_LO
     feature, threshold, fit, inter = (
         _pad_heap(a, h_pad)
         for a in (feature, threshold, fit, is_internal.astype(jnp.int32))
@@ -326,8 +370,8 @@ def _forest_predict_agg_seg_impl(
     grid = (pl.cdiv(n, block_obs), pl.cdiv(t, block_trees))
     kernel = functools.partial(
         _tree_predict_agg_seg_kernel,
-        max_depth=max_depth, lo_bits=lo_bits, n_lo=n_lo, n_hi=n_hi, d=d,
-        n_classes=n_classes, block_trees=block_trees, n_trees=t,
+        max_depth=max_depth, n_hi=n_hi, n_classes=n_classes,
+        block_trees=block_trees, n_trees=t,
     )
     tree_spec = lambda: pl.BlockSpec((block_trees, h_pad), lambda i, j: (j, 0))
     out = pl.pallas_call(
@@ -339,11 +383,11 @@ def _forest_predict_agg_seg_impl(
             pl.BlockSpec((block_trees, 1), lambda i, j: (j, 0)),
             tree_spec(), tree_spec(), tree_spec(), tree_spec(),
         ],
-        out_specs=pl.BlockSpec((block_obs, c_out), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c_out), jnp.float32),
+        out_specs=pl.BlockSpec((c_out, block_obs), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((c_out, n), jnp.float32),
         interpret=interpret,
     )(xb, obs_seg, tree_seg, feature, threshold, fit, inter)
-    return out[:, 0] if n_classes == 0 else out
+    return out[0] if n_classes == 0 else out.T
 
 
 def _forest_predict_agg_segmented_simple(
@@ -438,27 +482,39 @@ def segment_chunk_ranges(
 
 def _tree_predict_agg_seg_pipelined_kernel(
     chunk_lo_ref, chunk_hi_ref,  # SMEM (G,) int32 fori_loop bounds
+    tseg_ref,  # SMEM (T_pad,) int32 segment id per tree
     xb_ref, oseg_ref,  # VMEM blocks
-    code_hbm, fit_hbm, tseg_hbm,  # ANY/HBM, DMA'd per chunk
+    code_hbm, fit_hbm,  # ANY/HBM, DMA'd per chunk
     out_ref,
-    *, max_depth: int, lo_bits: int, n_lo: int, n_hi: int, d: int,
-    n_classes: int, block_trees: int, tb2: float,
+    *, max_depth: int, n_hi: int, n_classes: int, block_trees: int,
+    tb2: float,
 ):
     i = pl.program_id(0)
     lo = chunk_lo_ref[i]
     hi = chunk_hi_ref[i]
     bn = xb_ref.shape[0]
-    c_out = out_ref.shape[-1]
-    xbf = xb_ref[...].astype(jnp.float32)
+    c_out = out_ref.shape[0]
+    xb = xb_ref[...]
     osegs = oseg_ref[...]  # (1, BN)
+    tree_row = jax.lax.broadcasted_iota(jnp.int32, (block_trees, bn), 0)
 
-    def body(code_s, fit_s, tseg_s, sems):
-        # one DMA triple per (slot, chunk); fresh descriptors are cheap —
+    def node_at(code3):
+        def fields(idx):
+            c = _two_level_gather(code3, idx)
+            # power-of-two field scales: the reciprocal is exact, and so
+            # is the multiply/floor decode
+            fe = jnp.floor(c * (1.0 / tb2))
+            rem = c - fe * tb2
+            th = jnp.floor(rem * 0.5)
+            return fe.astype(jnp.int32), th, rem - 2.0 * th > 0.5
+
+        return fields
+
+    def body(code_s, fit_s, sems):
+        # one DMA pair per (slot, chunk); fresh descriptors are cheap —
         # start() and wait() pair up through the per-(slot, k) semaphore
         def dma(slot, ci, k):
-            src, dst = (
-                (code_hbm, code_s), (fit_hbm, fit_s), (tseg_hbm, tseg_s)
-            )[k]
+            src, dst = ((code_hbm, code_s), (fit_hbm, fit_s))[k]
             return pltpu.make_async_copy(
                 src.at[pl.ds(ci * block_trees, block_trees)],
                 dst.at[slot],
@@ -467,7 +523,7 @@ def _tree_predict_agg_seg_pipelined_kernel(
 
         @pl.when(lo < hi)
         def _():  # warm-up: fill slot 0 before the steady-state loop
-            for k in range(3):
+            for k in range(2):
                 dma(0, lo, k).start()
 
         def chunk_step(step, acc):
@@ -476,63 +532,36 @@ def _tree_predict_agg_seg_pipelined_kernel(
 
             @pl.when(ci + 1 < hi)
             def _():  # overlap: next chunk uploads while this one computes
-                for k in range(3):
+                for k in range(2):
                     dma((step + 1) % 2, ci + 1, k).start()
 
-            for k in range(3):
+            # the chunk's segment ids: scalar SMEM reads broadcast into
+            # rows (a (BT, 1) int32 DMA slice is not lane-aligned)
+            tseg = jnp.full((block_trees, bn), -1, jnp.int32)
+            for t in range(block_trees):
+                tseg = jnp.where(
+                    tree_row == t, tseg_ref[ci * block_trees + t], tseg
+                )
+            for k in range(2):
                 dma(cur, ci, k).wait()
-            code3 = code_s[cur].reshape(block_trees, n_hi, n_lo)
-            idx = jnp.zeros((block_trees, bn), jnp.int32)
-
-            def level(_, idx):
-                oh_hi = jax.nn.one_hot(
-                    idx >> lo_bits, n_hi, dtype=jnp.float32
-                )
-                oh_lo = jax.nn.one_hot(
-                    idx & (n_lo - 1), n_lo, dtype=jnp.float32
-                )
-                c = _two_level_gather(code3, oh_hi, oh_lo)
-                # power-of-two field scales: divide/floor decode is exact
-                fe = jnp.floor(c / tb2)
-                rem = c - fe * tb2
-                th = jnp.floor(rem * 0.5)
-                it = rem - 2.0 * th
-                ohf = jax.nn.one_hot(
-                    jnp.clip(fe.astype(jnp.int32), 0, d - 1), d,
-                    dtype=jnp.float32,
-                )
-                xv = jnp.einsum(
-                    "tnd,nd->tn", ohf, xbf,
-                    preferred_element_type=jnp.float32,
-                )
-                child = jnp.where(xv <= th, 2 * idx + 1, 2 * idx + 2)
-                return jnp.where(it > 0.5, child, idx)
-
-            idx = jax.lax.fori_loop(0, max_depth, level, idx)
-            fit3 = fit_s[cur].reshape(block_trees, n_hi, n_lo)
-            oh_hi = jax.nn.one_hot(idx >> lo_bits, n_hi, dtype=jnp.float32)
-            oh_lo = jax.nn.one_hot(idx & (n_lo - 1), n_lo, dtype=jnp.float32)
-            leaf = _two_level_gather(fit3, oh_hi, oh_lo)  # (BT, BN)
+            code3 = code_s[cur].reshape(block_trees, n_hi, N_LO)
+            idx = _traverse(
+                xb, node_at(code3), max_depth=max_depth, bt=block_trees
+            )
+            fit3 = fit_s[cur].reshape(block_trees, n_hi, N_LO)
+            leaf = _two_level_gather(fit3, idx)  # (BT, BN)
             # padding trees carry segment -1, which never matches a row
-            valid = (tseg_s[cur] == osegs).astype(jnp.float32)
-            if n_classes > 0:
-                oh_c = jax.nn.one_hot(
-                    leaf.astype(jnp.int32), n_classes, dtype=jnp.float32
-                )
-                return acc + (oh_c * valid[..., None]).sum(0)
-            return acc + (leaf * valid).sum(0)[:, None]
+            return acc + _aggregate(leaf, tseg == osegs, n_classes)
 
-        acc = jax.lax.fori_loop(
-            0, hi - lo, chunk_step, jnp.zeros((bn, c_out), jnp.float32)
+        out_ref[...] = jax.lax.fori_loop(
+            0, hi - lo, chunk_step, jnp.zeros((c_out, bn), jnp.float32)
         )
-        out_ref[...] = acc
 
     pl.run_scoped(
         body,
-        pltpu.VMEM((2, block_trees, n_hi * n_lo), jnp.float32),
-        pltpu.VMEM((2, block_trees, n_hi * n_lo), jnp.float32),
-        pltpu.VMEM((2, block_trees, 1), jnp.int32),
-        pltpu.SemaphoreType.DMA((2, 3)),
+        pltpu.VMEM((2, block_trees, n_hi * N_LO), jnp.float32),
+        pltpu.VMEM((2, block_trees, n_hi * N_LO), jnp.float32),
+        pltpu.SemaphoreType.DMA((2, 2)),
     )
 
 
@@ -549,16 +578,16 @@ def _forest_predict_agg_seg_pipelined_impl(
 ):
     t_pad, h = code.shape
     n, d = xb.shape
-    lo_bits, n_lo, n_hi = _heap_split(h)
-    h_pad = n_lo * n_hi
+    n_hi = _heap_split(h)
+    h_pad = n_hi * N_LO
     code = _pad_heap(code, h_pad)
     fit = _pad_heap(fit, h_pad)
     c_out = n_classes if n_classes > 0 else 1
     grid = (pl.cdiv(n, block_obs),)
     kernel = functools.partial(
         _tree_predict_agg_seg_pipelined_kernel,
-        max_depth=max_depth, lo_bits=lo_bits, n_lo=n_lo, n_hi=n_hi, d=d,
-        n_classes=n_classes, block_trees=block_trees, tb2=float(tb2),
+        max_depth=max_depth, n_hi=n_hi, n_classes=n_classes,
+        block_trees=block_trees, tb2=float(tb2),
     )
     out = pl.pallas_call(
         kernel,
@@ -566,20 +595,17 @@ def _forest_predict_agg_seg_pipelined_impl(
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_obs, d), lambda i: (i, 0)),
             pl.BlockSpec((1, block_obs), lambda i: (0, i)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_obs, c_out), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c_out), jnp.float32),
+        out_specs=pl.BlockSpec((c_out, block_obs), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((c_out, n), jnp.float32),
         interpret=interpret,
-    )(
-        chunk_lo, chunk_hi, xb, obs_seg.reshape(1, n), code, fit,
-        tree_seg.reshape(t_pad, 1),
-    )
-    return out[:, 0] if n_classes == 0 else out
+    )(chunk_lo, chunk_hi, tree_seg, xb, obs_seg.reshape(1, n), code, fit)
+    return out[0] if n_classes == 0 else out.T
 
 
 def forest_predict_agg_segmented_packed(
@@ -736,8 +762,8 @@ def _forest_predict_agg_impl(
 ):
     t, h = feature.shape
     n, d = xb.shape
-    lo_bits, n_lo, n_hi = _heap_split(h)
-    h_pad = n_lo * n_hi
+    n_hi = _heap_split(h)
+    h_pad = n_hi * N_LO
     feature, threshold, fit, inter = (
         _pad_heap(a, h_pad)
         for a in (feature, threshold, fit, is_internal.astype(jnp.int32))
@@ -748,8 +774,8 @@ def _forest_predict_agg_impl(
     grid = (pl.cdiv(n, block_obs), pl.cdiv(t, block_trees))
     kernel = functools.partial(
         _tree_predict_agg_kernel,
-        max_depth=max_depth, lo_bits=lo_bits, n_lo=n_lo, n_hi=n_hi, d=d,
-        n_classes=n_classes, block_trees=block_trees, n_trees=t,
+        max_depth=max_depth, n_hi=n_hi, n_classes=n_classes,
+        block_trees=block_trees, n_trees=t,
     )
     tree_spec = lambda: pl.BlockSpec((block_trees, h_pad), lambda i, j: (j, 0))
     out = pl.pallas_call(
@@ -759,11 +785,11 @@ def _forest_predict_agg_impl(
             pl.BlockSpec((block_obs, d), lambda i, j: (i, 0)),
             tree_spec(), tree_spec(), tree_spec(), tree_spec(),
         ],
-        out_specs=pl.BlockSpec((block_obs, c_out), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c_out), jnp.float32),
+        out_specs=pl.BlockSpec((c_out, block_obs), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((c_out, n), jnp.float32),
         interpret=interpret,
     )(xb, feature, threshold, fit, inter)
-    return out[:, 0] if n_classes == 0 else out
+    return out[0] if n_classes == 0 else out.T
 
 
 def forest_predict_agg(

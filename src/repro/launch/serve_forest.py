@@ -44,8 +44,11 @@ def main() -> None:
     from ..core.forest_codec import compress_forest
     from ..data.tabular import TabularSpec, make_dataset
     from ..forest import fit_binner, to_compact_forest, train_forest
+    from ..runtime.compile_cache import enable_compile_cache
     from ..serving import ForestServer
+    from ..serving.parity import count_mismatches, served_tolerance
 
+    enable_compile_cache()
     spec = TabularSpec("serve", args.rows, args.features, args.task, 2, 2)
     x, y, cat = make_dataset(spec, seed=args.seed)
     binner = fit_binner(x, categorical=cat, n_bins=32)
@@ -65,6 +68,7 @@ def main() -> None:
     pred = server.predict(xb[: args.batch], block_trees=args.block_trees)
     t_serve = time.time() - t0
     ref = predict_compressed(comp, xb[: args.batch])
+    mismatch = count_mismatches(pred, ref, served_tolerance(comp))
     agree = float((pred == ref).mean()) if args.task == "classification" \
         else float(np.max(np.abs(pred - ref)))
     print(
@@ -72,9 +76,12 @@ def main() -> None:
         f"({blob_bytes} compressed bytes)\n"
         f"serve {args.batch} rows: {t_serve * 1e3:.1f} ms "
         f"({args.batch / t_serve:.0f} rows/s), "
-        f"agreement vs predict_compressed: {agree}\n"
+        f"agreement vs predict_compressed: {agree} "
+        f"({mismatch} rows outside tolerance)\n"
         f"session: {server.stats()['plan_cache']}"
     )
+    if mismatch:
+        raise SystemExit(f"{mismatch} rows disagree with predict_compressed")
 
 
 if __name__ == "__main__":

@@ -184,9 +184,12 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from ..runtime.compile_cache import enable_compile_cache
     from ..serving import ForestServer
+    from ..serving.parity import count_mismatches, served_tolerance
     from ..store import build_store, make_request_batch, make_synthetic_fleet
 
+    enable_compile_cache()
     fleet = make_synthetic_fleet(
         args.users, task=args.task, max_depth=args.depth, seed=args.seed
     )
@@ -206,13 +209,13 @@ def main() -> None:
     t_serve = time.time() - t0
     n_rows = sum(len(x) for _, x in requests)
 
-    mismatch = 0
-    for (user_id, x), p in zip(requests[:8], preds[:8]):
-        ref = store.predict(user_id, x)
-        if args.task == "classification":
-            mismatch += int((p != ref).sum())
-        else:
-            mismatch += int(np.max(np.abs(p - ref)) > 1e-4)
+    mismatch = sum(
+        count_mismatches(
+            p, store.predict(user_id, x),
+            served_tolerance(store.hydrate(user_id)),
+        )
+        for (user_id, x), p in zip(requests, preds)
+    )
     stats = server.stats()
     stats["tile_cache"].pop("per_user", None)  # too chatty for the demo
     print(
@@ -226,9 +229,11 @@ def main() -> None:
         f"ragged batch: {len(requests)} requests / {n_rows} rows in "
         f"{t_serve * 1e3:.1f} ms ({n_rows / t_serve:.0f} rows/s)\n"
         f"session stats: {stats}\n"
-        f"parity vs per-user predict_compressed (8 requests): "
-        f"{mismatch} mismatches"
+        f"parity vs per-user predict_compressed ({len(requests)} "
+        f"requests): {mismatch} mismatches"
     )
+    if mismatch:
+        raise SystemExit(f"{mismatch} rows disagree with predict_compressed")
 
 
 if __name__ == "__main__":

@@ -202,13 +202,16 @@ def build_sharded_pack(store, plan: ServePlan) -> ShardedPack:
     arrays at a stale narrower width), bin-pack users by tree count, then
     gather each shard with GLOBAL segment ids."""
     import jax
-    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
 
-    from ..kernels.tree_predict.ops import partition_segments_by_load
+    from ..kernels.tree_predict.ops import (
+        partition_segments_by_load,
+        shard_mesh,
+    )
     from ..kernels.tree_predict.tree_predict import segment_chunk_ranges
 
     bt = plan.engine.block_trees
-    n_dev = len(jax.devices())
+    n_dev = plan.engine.n_devices
     store.arena_ensure(list(plan.users), bt)
     shards = partition_segments_by_load(plan.seg_trees, n_dev)
     # per-shard users ascend by segment id: sorted rows keep ranges tight
@@ -235,8 +238,21 @@ def build_sharded_pack(store, plan: ServePlan) -> ShardedPack:
         tsegs.append(tseg)
         los.append(lo)
         his.append(hi)
+    # each shard goes straight to its own mesh device (never stacked on
+    # the arena's device first)
+    mesh = shard_mesh(n_dev)
+    on_mesh = NamedSharding(mesh, PartitionSpec("shard"))
+
+    def place(parts):
+        shape = (len(parts),) + tuple(parts[0].shape)
+        return jax.make_array_from_single_device_arrays(
+            shape, on_mesh,
+            [jax.device_put(p[None], dev)
+             for p, dev in zip(parts, mesh.devices.flat)],
+        )
+
     return ShardedPack(
-        jnp.stack(codes), jnp.stack(fits), np.stack(tsegs),
+        place(codes), place(fits), np.stack(tsegs),
         np.stack(los), np.stack(his), max_depth, bo,
     )
 

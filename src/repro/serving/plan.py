@@ -17,9 +17,11 @@ import numpy as np
 
 from .pack import batch_layout
 
-#: Per-engine (block_trees, block_obs) sweet spots (PR 3 tuning).
+#: Per-engine (block_trees, block_obs).  Tuned on the CPU backend, except
+#: that ``simple`` is held at (8, 128): at (32, 256) its (BT, BN, 128)
+#: gather temporaries overflow a v5e core's scoped VMEM.
 ENGINE_BLOCKS = {
-    "simple": (32, 256),
+    "simple": (8, 128),
     "pipelined": (8, 128),
     "sharded": (8, 128),
 }
@@ -43,6 +45,7 @@ class EngineChoice:
     block_trees: int
     block_obs: int
     reason: str = field(default="", compare=False)
+    n_devices: int = 1  # devices the sharded engine spans
 
 
 @dataclass
@@ -52,7 +55,7 @@ class ServePlan:
     padded shapes, engine choice — plus the hashable ``signature`` the
     cross-batch ``PlanCache`` keys gathered packs by."""
 
-    signature: tuple  # ((user, rows)..., engine, block_trees, block_obs)
+    signature: tuple  # ((user, rows)..., engine, blocks, n_devices)
     user_tokens: tuple[int, ...]  # per-user versions (aligned with users):
     # the plan's validity token — only a change to one of ITS users'
     # registrations makes it stale (partial invalidation)
@@ -81,13 +84,20 @@ def choose_engine(
     engine: str | None = None,
     block_trees: int | None = None,
     block_obs: int | None = None,
+    n_devices: int | None = None,
 ) -> EngineChoice:
     """Resolve the engine for a batch.  ``engine=None`` asks the cost
     model: ``simple`` when the store schema cannot use the fused arena,
     ``sharded`` when >1 device AND the greedy bin-pack predicts at least
     ``MIN_SHARD_SPEEDUP`` over one device, else ``pipelined``.  Explicit
     names are validated but honoured (the escape hatch the legacy string
-    kwargs become)."""
+    kwargs become).  ``n_devices`` (default: all) is how many of
+    ``jax.devices()`` the sharded engine may span."""
+    import jax
+
+    n_dev = len(jax.devices())
+    if n_devices is not None:
+        n_dev = min(int(n_devices), n_dev)
     if engine is not None:
         if engine not in ENGINE_BLOCKS:
             raise ValueError(f"unknown serving engine {engine!r}")
@@ -102,9 +112,6 @@ def choose_engine(
         engine = "simple"
         reason = "store schema cannot pack the fused arena layout"
     else:
-        import jax
-
-        n_dev = len(jax.devices())
         total_trees = int(np.asarray(seg_trees).sum())
         if n_dev <= 1:
             engine, reason = "pipelined", "single device"
@@ -136,6 +143,7 @@ def choose_engine(
         bt_default if block_trees is None else int(block_trees),
         bo_default if block_obs is None else int(block_obs),
         reason,
+        n_dev if engine == "sharded" else 1,
     )
 
 
@@ -146,6 +154,7 @@ def build_plan(
     engine: str | None = None,
     block_trees: int | None = None,
     block_obs: int | None = None,
+    n_devices: int | None = None,
 ) -> ServePlan:
     """Compile a batch signature into a ``ServePlan`` (pure host work)."""
     request_users = tuple(request_users)
@@ -160,6 +169,7 @@ def build_plan(
     choice = choose_engine(
         store, seg_trees, n_rows,
         engine=engine, block_trees=block_trees, block_obs=block_obs,
+        n_devices=n_devices,
     )
     t = int(seg_trees.sum())
     t_pad = max(
@@ -168,7 +178,7 @@ def build_plan(
     bo = min(choice.block_obs, n_rows) if n_rows else choice.block_obs
     signature = (
         tuple(zip(request_users, row_counts)),
-        choice.name, choice.block_trees, choice.block_obs,
+        choice.name, choice.block_trees, choice.block_obs, choice.n_devices,
     )
     return ServePlan(
         signature=signature,

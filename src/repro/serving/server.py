@@ -169,11 +169,17 @@ class ForestServer:
         max_retries: int = 3,
         retry_backoff_s: float = 0.01,
         repairer: "Callable[[str], bool] | None" = None,
+        n_devices: int | None = None,
     ) -> None:
         self.store = store
+        # how many of jax.devices() the sharded engine may span (None: all)
+        self.n_devices = n_devices
         self.plan_cache = PlanCache(plan_cache_size)
         self.interpret = interpret
         self.engine_counts: Counter[str] = Counter()
+        # batches whose kernel ran in Pallas interpret mode (the CPU
+        # backend's default); a chip deployment expects 0
+        self.interpreted_batches = 0
         # per-engine execute wall-times (bounded window per engine),
         # surfaced as stats()["engine_timings"] for SLO dashboards
         self._engine_times: dict[str, deque[float]] = {}
@@ -254,6 +260,7 @@ class ForestServer:
             plan = build_plan(
                 self.store, request_users, row_counts,
                 engine=engine, block_trees=block_trees, block_obs=block_obs,
+                n_devices=self.n_devices,
             )
             self.plan_cache.put_plan(key, token, plan)
         return plan
@@ -311,8 +318,13 @@ class ForestServer:
         xb = concat_rows(X)
         if interpret is None:
             interpret = self.interpret
+        if interpret is None:
+            import jax
+
+            interpret = jax.default_backend() == "cpu"
         name = plan.engine.name
         self.engine_counts[name] += 1
+        self.interpreted_batches += bool(interpret)
         residency = getattr(self.store, "residency", None)
         if residency is not None:
             # absorb prefetch-staged deltas on THIS (serving) thread —
@@ -668,6 +680,7 @@ class ForestServer:
                 "integrity_failures": self.integrity_failures,
                 "transient_retries": self.transient_retries,
                 "degraded_batches": self.degraded_batches,
+                "interpreted_batches": self.interpreted_batches,
                 "repair_attempts": self.repair_attempts,
                 "repairs": self.repairs,
                 "last_repair_error": self.last_repair_error,
