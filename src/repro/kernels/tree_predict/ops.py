@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...runtime.trace import span
 from .tree_predict import forest_predict, forest_predict_agg
 
 
@@ -179,7 +180,7 @@ def forest_predict_agg_segmented_sharded(
     Vote counts stay integer-exact under the reduction (float32 holds
     integers exactly below 2**24), so classification results are bit-exact
     against the single-device engines."""
-    from .tree_predict import _F32_EXACT_INT, _validate_f32_exact
+    from .tree_predict import _F32_EXACT_INT, _int32, _validate_f32_exact
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
@@ -193,15 +194,17 @@ def forest_predict_agg_segmented_sharded(
     if n_classes > 0 and n_classes >= _F32_EXACT_INT:
         raise ValueError("n_classes >= 2**24 overflows float32 vote counts")
     arrays = {"xb": xb} if isinstance(xb, np.ndarray) else {}
-    _validate_f32_exact(max_depth, d, **arrays)
+    with span("serve.prep"):
+        _validate_f32_exact(max_depth, d, **arrays)
     fn = _sharded_callable(
         s, max_depth, n_classes, block_trees, min(block_obs, n), int(tb2),
         interpret,
     )
-    out = fn(
-        jnp.asarray(xb, jnp.int32), jnp.asarray(obs_seg, jnp.int32),
-        jnp.asarray(code), jnp.asarray(fit),
-        jnp.asarray(tree_seg, jnp.int32), jnp.asarray(chunk_lo, jnp.int32),
-        jnp.asarray(chunk_hi, jnp.int32),
-    )
+    with span("tree_predict.upload"):
+        args = jax.device_put([
+            _int32(xb), _int32(obs_seg), code, fit, _int32(tree_seg),
+            _int32(chunk_lo), _int32(chunk_hi),
+        ])
+    with span("tree_predict.launch"):
+        out = fn(*args)
     return out[:, 0] if n_classes == 0 else out

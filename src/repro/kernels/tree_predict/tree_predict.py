@@ -73,7 +73,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...runtime.trace import span
+
 _F32_EXACT_INT = 1 << 24  # float32 has a 24-bit significand
+
+
+def _int32(a):
+    """``a`` as int32: a device array stays on the device, anything else
+    becomes a host array (for one batched ``jax.device_put``)."""
+    if isinstance(a, jax.Array):
+        return a if a.dtype == jnp.int32 else a.astype(jnp.int32)
+    return np.asarray(a, np.int32)
 
 
 def _validate_f32_exact(max_depth: int, d: int, **arrays) -> None:
@@ -291,6 +301,7 @@ def _forest_predict_impl(
         ),
         out_shape=jax.ShapeDtypeStruct((t, n), jnp.float32),
         interpret=interpret,
+        name="tree_predict",
     )(xb, feature, threshold, fit, inter)
 
 
@@ -386,6 +397,7 @@ def _forest_predict_agg_seg_impl(
         out_specs=pl.BlockSpec((c_out, block_obs), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((c_out, n), jnp.float32),
         interpret=interpret,
+        name="tree_predict_agg_seg",
     )(xb, obs_seg, tree_seg, feature, threshold, fit, inter)
     return out[0] if n_classes == 0 else out.T
 
@@ -399,18 +411,23 @@ def _forest_predict_agg_segmented_simple(
     and serving baseline."""
     t, _ = feature.shape
     n, d = xb.shape
-    _validate_f32_exact(
-        max_depth, d, feature=feature, threshold=threshold, xb=xb
-    )
+    with span("serve.prep"):
+        _validate_f32_exact(
+            max_depth, d, feature=feature, threshold=threshold, xb=xb
+        )
     if n_classes > 0 and n_classes >= _F32_EXACT_INT:
         raise ValueError("n_classes >= 2**24 overflows float32 vote counts")
-    obs_seg = jnp.asarray(obs_seg, jnp.int32).reshape(1, n)
-    tree_seg = jnp.asarray(tree_seg, jnp.int32).reshape(t, 1)
-    return _forest_predict_agg_seg_impl(
-        xb, obs_seg, tree_seg, feature, threshold, fit, is_internal,
-        max_depth, n_classes, min(block_trees, t), min(block_obs, n),
-        interpret,
-    )
+    with span("tree_predict.upload"):
+        args = jax.device_put([
+            _int32(xb), _int32(obs_seg).reshape(1, n),
+            _int32(tree_seg).reshape(t, 1), feature, threshold, fit,
+            is_internal,
+        ])
+    with span("tree_predict.launch"):
+        return _forest_predict_agg_seg_impl(
+            *args, max_depth, n_classes, min(block_trees, t),
+            min(block_obs, n), interpret,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -604,6 +621,7 @@ def _forest_predict_agg_seg_pipelined_impl(
         out_specs=pl.BlockSpec((c_out, block_obs), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((c_out, n), jnp.float32),
         interpret=interpret,
+        name="tree_predict_agg_seg_pipelined",
     )(chunk_lo, chunk_hi, tree_seg, xb, obs_seg.reshape(1, n), code, fit)
     return out[0] if n_classes == 0 else out.T
 
@@ -645,13 +663,20 @@ def forest_predict_agg_segmented_packed(
     arrays = {"xb": xb}
     if isinstance(code, np.ndarray):
         arrays["code"] = code
-    _validate_f32_exact(max_depth, d, **arrays)
-    return _forest_predict_agg_seg_pipelined_impl(
-        xb, jnp.asarray(obs_seg, jnp.int32), code, fit,
-        jnp.asarray(tree_seg, jnp.int32), jnp.asarray(chunk_lo, jnp.int32),
-        jnp.asarray(chunk_hi, jnp.int32), max_depth, n_classes, block_trees,
-        min(block_obs, n), int(tb2), interpret,
-    )
+    with span("serve.prep"):
+        _validate_f32_exact(max_depth, d, **arrays)
+    # rows, segment ids and ranges go up in one batched transfer; code and
+    # fit are device arrays in serving (the arena's gathers)
+    with span("tree_predict.upload"):
+        xb, obs_seg, tree_seg, chunk_lo, chunk_hi = jax.device_put([
+            _int32(a) for a in (xb, obs_seg, tree_seg, chunk_lo, chunk_hi)
+        ])
+    with span("tree_predict.launch"):
+        return _forest_predict_agg_seg_pipelined_impl(
+            xb, obs_seg, code, fit, tree_seg, chunk_lo, chunk_hi,
+            max_depth, n_classes, block_trees, min(block_obs, n), int(tb2),
+            interpret,
+        )
 
 
 def _is_concrete(*arrays) -> bool:
@@ -736,9 +761,12 @@ def forest_predict_agg_segmented(
             chunk_lo, chunk_hi = segment_chunk_ranges(
                 oseg_h, tseg_h, block_trees, block_obs
             )
+            with span("tree_predict.upload"):
+                code = jnp.asarray(code)
+                fit = jnp.asarray(fit, jnp.float32)
             return forest_predict_agg_segmented_packed(
-                xb, oseg_h, jnp.asarray(code), jnp.asarray(fit, jnp.float32),
-                tseg_h, chunk_lo, chunk_hi, max_depth, 2 * tb,
+                xb, oseg_h, code, fit, tseg_h, chunk_lo, chunk_hi,
+                max_depth, 2 * tb,
                 n_classes=n_classes, block_trees=block_trees,
                 block_obs=block_obs, interpret=interpret,
             )
@@ -788,6 +816,7 @@ def _forest_predict_agg_impl(
         out_specs=pl.BlockSpec((c_out, block_obs), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((c_out, n), jnp.float32),
         interpret=interpret,
+        name="tree_predict_agg",
     )(xb, feature, threshold, fit, inter)
     return out[0] if n_classes == 0 else out.T
 

@@ -18,6 +18,7 @@ from typing import NamedTuple
 
 import numpy as np
 
+from ..runtime.trace import span
 from .pack import pack_host_tiles
 from .plan import ServePlan
 
@@ -25,6 +26,14 @@ from .plan import ServePlan
 def _n_classes(store) -> int:
     shared = store.shared
     return shared.n_classes if shared.task == "classification" else 0
+
+
+def _unpermute(plan: ServePlan, out: np.ndarray) -> np.ndarray:
+    """The aggregate of the segment-sorted rows back in request order."""
+    with span("serve.finalize"):
+        total = np.empty_like(out)
+        total[plan.order] = out
+    return total
 
 
 class PipelinedPack(NamedTuple):
@@ -69,9 +78,10 @@ def run_simple(
 
     block_trees = plan.engine.block_trees
     block_obs = plan.engine.block_obs
-    tree_pack, max_depth, _seg_trees = pack_host_tiles(
-        store, plan.users, block_trees
-    )
+    with span("serve.pack"):
+        tree_pack, max_depth, _seg_trees = pack_host_tiles(
+            store, plan.users, block_trees
+        )
     feature, threshold, fit, is_internal, tree_seg = tree_pack
     n_classes = _n_classes(store)
     n, c_out = plan.n_rows, max(n_classes, 1)
@@ -84,15 +94,15 @@ def run_simple(
     # chunk-boundary users).  Spans are padded to block_obs multiples (rows)
     # and block_trees (trees) with non-matching sentinel segments, so the
     # jitted kernel sees a handful of distinct shapes, not one per span.
-    xb_s = np.ascontiguousarray(xb[plan.order])
     oseg_s = plan.oseg_s
-    n_segs = plan.n_users
-    seg_start = np.searchsorted(oseg_s, np.arange(n_segs))
-    seg_end = np.searchsorted(oseg_s, np.arange(n_segs), side="right")
-
-    total_sorted = np.zeros(
-        (n, c_out) if n_classes > 0 else (n,), np.float64
-    )
+    with span("serve.prep"):
+        xb_s = np.ascontiguousarray(xb[plan.order])
+        n_segs = plan.n_users
+        seg_start = np.searchsorted(oseg_s, np.arange(n_segs))
+        seg_end = np.searchsorted(oseg_s, np.arange(n_segs), side="right")
+        total_sorted = np.zeros(
+            (n, c_out) if n_classes > 0 else (n,), np.float64
+        )
     parts: list[tuple[int, int, object]] = []
     for lo in range(0, t, block_trees):
         hi = min(lo + block_trees, t)
@@ -133,11 +143,10 @@ def run_simple(
             engine="simple",
         )  # dispatched async; host keeps slicing/submitting
         parts.append((r0p, r1p, part))
-    for r0p, r1p, part in parts:
-        total_sorted[r0p:r1p] += np.asarray(part, np.float64)
-    total = np.empty_like(total_sorted)
-    total[plan.order] = total_sorted
-    return total
+    with span("serve.wait"):
+        for r0p, r1p, part in parts:
+            total_sorted[r0p:r1p] += np.asarray(part, np.float64)
+    return _unpermute(plan, total_sorted)
 
 
 # ---------------------------------------------------------------------------
@@ -177,7 +186,8 @@ def run_pipelined(
         forest_predict_agg_segmented_packed,
     )
 
-    xb_s = np.ascontiguousarray(xb[plan.order])
+    with span("serve.prep"):
+        xb_s = np.ascontiguousarray(xb[plan.order])
     out = forest_predict_agg_segmented_packed(
         xb_s, plan.oseg_s, pack.code, pack.fit, pack.tree_seg,
         pack.chunk_lo, pack.chunk_hi, pack.max_depth, store.arena.tb2,
@@ -185,10 +195,9 @@ def run_pipelined(
         block_trees=plan.engine.block_trees, block_obs=pack.block_obs,
         interpret=interpret,
     )
-    out = np.asarray(out, np.float64)
-    total = np.empty_like(out)
-    total[plan.order] = out
-    return total
+    with span("serve.wait"):
+        out = np.asarray(out, np.float64)
+    return _unpermute(plan, out)
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +278,8 @@ def run_sharded(
         forest_predict_agg_segmented_sharded,
     )
 
-    xb_s = np.ascontiguousarray(xb[plan.order])
+    with span("serve.prep"):
+        xb_s = np.ascontiguousarray(xb[plan.order])
     out = forest_predict_agg_segmented_sharded(
         xb_s, plan.oseg_s, pack.code, pack.fit, pack.tree_seg,
         pack.chunk_lo, pack.chunk_hi, pack.max_depth, store.arena.tb2,
@@ -277,7 +287,6 @@ def run_sharded(
         block_trees=plan.engine.block_trees, block_obs=pack.block_obs,
         interpret=interpret,
     )
-    out = np.asarray(out, np.float64)
-    total = np.empty_like(out)
-    total[plan.order] = out
-    return total
+    with span("serve.wait"):
+        out = np.asarray(out, np.float64)
+    return _unpermute(plan, out)

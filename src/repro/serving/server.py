@@ -43,12 +43,17 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from ..runtime.trace import span
 from ..store.runtime import ForestStore, TileCache, make_schema_arena
 from . import engines
 from .cache import PlanCache
 from .plan import ENGINE_BLOCKS, ServePlan, build_plan
 
 Request = tuple[str, np.ndarray]
+
+
+def _n_rows(requests: Sequence[Request]) -> int:
+    return sum(len(x) for _, x in requests)
 
 
 @dataclass
@@ -176,6 +181,8 @@ class ForestServer:
         self.n_devices = n_devices
         self.plan_cache = PlanCache(plan_cache_size)
         self.interpret = interpret
+        # calls into serve / serve_safe, the ``seq`` of their trace spans
+        self.calls = 0
         self.engine_counts: Counter[str] = Counter()
         # batches whose kernel ran in Pallas interpret mode (the CPU
         # backend's default); a chip deployment expects 0
@@ -242,6 +249,10 @@ class ForestServer:
         its row COUNT — plans depend only on the batch signature, so they
         can be built (and cached) without the data.  Memoized across
         batches; invalidated when the store registry changes."""
+        with span("serve.plan"):
+            return self._plan(requests, engine, block_trees, block_obs)
+
+    def _plan(self, requests, engine, block_trees, block_obs) -> ServePlan:
         request_users = tuple(u for u, _ in requests)
         row_counts = tuple(
             int(x) if isinstance(x, (int, np.integer)) else len(x)
@@ -294,28 +305,13 @@ class ForestServer:
         vote / ensemble mean), matching per-user ``predict_compressed``
         (vote counts are integer-exact; the regression mean accumulates in
         float32 on device)."""
-        if len(X) != len(plan.row_counts):
-            raise ValueError(
-                f"plan covers {len(plan.row_counts)} requests, "
-                f"got {len(X)} row blocks"
+        with span("serve.prep"):
+            xb = self._rows(plan, X)
+        if xb is None:
+            return (
+                [] if not plan.request_users
+                else [np.zeros(len(x), np.float64) for x in X]
             )
-        for i, (x, n) in enumerate(zip(X, plan.row_counts)):
-            if len(x) != n:
-                raise ValueError(
-                    f"request {i}: plan expects {n} rows, got {len(x)}"
-                )
-        if self._plan_token(plan.users) != plan.user_tokens:
-            raise ValueError(
-                "stale plan: one of the plan's users was re-registered "
-                "or migrated since it was built — call plan() again"
-            )
-        if not plan.request_users:
-            return []
-        if plan.n_rows == 0:
-            return [np.zeros(len(x), np.float64) for x in X]
-        from .pack import concat_rows
-
-        xb = concat_rows(X)
         if interpret is None:
             interpret = self.interpret
         if interpret is None:
@@ -351,6 +347,30 @@ class ForestServer:
         self._record_timing(name, time.perf_counter() - t0)
         return out
 
+    def _rows(self, plan: ServePlan, X) -> np.ndarray | None:
+        """Check ``X`` against the plan and concatenate it into the (N, d)
+        row block; ``None`` when the plan has no rows to serve."""
+        if len(X) != len(plan.row_counts):
+            raise ValueError(
+                f"plan covers {len(plan.row_counts)} requests, "
+                f"got {len(X)} row blocks"
+            )
+        for i, (x, n) in enumerate(zip(X, plan.row_counts)):
+            if len(x) != n:
+                raise ValueError(
+                    f"request {i}: plan expects {n} rows, got {len(x)}"
+                )
+        if self._plan_token(plan.users) != plan.user_tokens:
+            raise ValueError(
+                "stale plan: one of the plan's users was re-registered "
+                "or migrated since it was built — call plan() again"
+            )
+        if not plan.request_users or plan.n_rows == 0:
+            return None
+        from .pack import concat_rows
+
+        return concat_rows(X)
+
     def _record_timing(self, engine: str, elapsed_s: float) -> None:
         times = self._engine_times.get(engine)
         if times is None:
@@ -385,6 +405,10 @@ class ForestServer:
         eager sweep still drops every pack holding an evicted user, so
         gathered device copies never outlive the arena's capacity
         accounting."""
+        with span("serve.pack"):
+            return self._pack(plan)
+
+    def _pack(self, plan: ServePlan):
         arena = self.store.arena
         self.plan_cache.sweep_packs(self._pack_token)
         pack = self.plan_cache.get_pack(
@@ -411,14 +435,15 @@ class ForestServer:
     def _finalize(self, plan: ServePlan, total: np.ndarray):
         task = self.store.shared.task
         out: list[np.ndarray] = []
-        for user_id, sl in zip(plan.request_users, plan.row_slices):
-            if task == "classification":
-                out.append(total[sl].argmax(-1).astype(np.float64))
-            else:
-                out.append(
-                    total[sl].astype(np.float64)
-                    / max(self.store.n_trees(user_id), 1)
-                )
+        with span("serve.finalize"):
+            for user_id, sl in zip(plan.request_users, plan.row_slices):
+                if task == "classification":
+                    out.append(total[sl].argmax(-1).astype(np.float64))
+                else:
+                    out.append(
+                        total[sl].astype(np.float64)
+                        / max(self.store.n_trees(user_id), 1)
+                    )
         return out
 
     # ---------------- conveniences ----------------------------------------
@@ -434,6 +459,13 @@ class ForestServer:
         ``serve_safe`` is the fault-isolating variant."""
         if not requests:
             return []
+        self.calls += 1
+        with span("serve.call", seq=self.calls, rows=_n_rows(requests)):
+            return self._serve(
+                requests, engine, block_trees, block_obs, interpret
+            )
+
+    def _serve(self, requests, engine, block_trees, block_obs, interpret):
         plan = self.plan(
             requests, engine=engine,
             block_trees=block_trees, block_obs=block_obs,
@@ -547,7 +579,7 @@ class ForestServer:
 
         for attempt in range(self.max_retries + 1):
             try:
-                return self.serve(requests, **kwargs), False
+                return self._serve(requests, **kwargs), False
             except TransientError:
                 self.transient_retries += 1
                 if attempt < self.max_retries:
@@ -555,7 +587,7 @@ class ForestServer:
         self.degraded_batches += 1
         kwargs = dict(kwargs)
         kwargs["engine"] = "simple"
-        return self.serve(requests, **kwargs), True
+        return self._serve(requests, **kwargs), True
 
     def serve_safe(
         self,
@@ -581,6 +613,14 @@ class ForestServer:
         of failing."""
         if not requests:
             return []
+        self.calls += 1
+        with span("serve.call", seq=self.calls, rows=_n_rows(requests)):
+            return self._serve_safe(
+                requests, engine, block_trees, block_obs, interpret
+            )
+
+    def _serve_safe(self, requests, engine, block_trees, block_obs,
+                    interpret) -> list[RequestStatus]:
         self._refresh_quarantine()
         probe_bt = block_trees or self._probe_block_trees(engine)
         for u in dict.fromkeys(u for u, _ in requests):
