@@ -17,7 +17,9 @@ Phases (data made from ``--seed``; nothing is read from outside the repo):
   through ``plan``/``execute``.
 * ``sharded`` (``--chips 4`` only) — the ``fleet`` phase with the engine
   forced to ``sharded`` over a 4-device mesh, compared with the one-chip
-  ``pipelined`` answer and with ``predict_compressed``.
+  ``pipelined`` answer and with ``predict_compressed``; then the same for
+  a classification fleet (7 classes, depth 8), which the kernel's
+  ``gemm`` body serves.
 
 Every prediction is compared with ``predict_compressed`` and with a plain
 numpy walk of the uncompressed forest: votes must be equal, regression
@@ -43,6 +45,7 @@ SINGLE = {"trees": 500, "depth": 10, "classes": 7, "bins": 32,
           "batches": (1, 256, 4096)}
 FLEET = {"users": 128, "trees": (16, 48), "depth": 8, "bins": 32,
          "n_batches": 3, "requests": 64, "rows": (16, 256)}
+CLASS_FLEET = dict(FLEET, task="classification", classes=7)
 
 
 def forest_reference(forest, xb: np.ndarray) -> np.ndarray:
@@ -215,13 +218,29 @@ def build_fleet(seed: int, sizes: dict):
 
     t0 = time.perf_counter()
     fleet = make_synthetic_fleet(
-        sizes["users"], task="regression", n_trees=sizes["trees"], d=32,
-        n_bins=sizes["bins"], max_depth=sizes["depth"], seed=seed,
+        sizes["users"], task=sizes.get("task", "regression"),
+        n_trees=sizes["trees"], d=32, n_bins=sizes["bins"],
+        max_depth=sizes["depth"], n_classes=sizes.get("classes", 2),
+        seed=seed,
     )
     store = build_store(fleet, seed=seed)
     build_s = time.perf_counter() - t0
     xb = binned_rows("liberty_reg", sizes["bins"], seed)
     return fleet, store, fleet_batches(store, xb, sizes, seed + 1), build_s
+
+
+def kernel_body(store, plan, sizes: dict) -> str:
+    """The pipelined kernel's traversal body for ``plan``'s shapes."""
+    from repro.kernels.tree_predict.tree_predict import select_path
+
+    return select_path(
+        sizes["depth"], sizes.get("classes", 0), store.arena.tb2, 32,
+        plan.engine.block_trees, min(plan.engine.block_obs, plan.n_rows),
+    )
+
+
+def fleet_task(fleet) -> str:
+    return next(iter(fleet.values())).meta.task
 
 
 def by_user(batches, preds_by_batch) -> dict[str, np.ndarray]:
@@ -309,6 +328,9 @@ def phase_sharded(seed: int, n_devices: int, sizes: dict = FLEET,
     plan = sharded.plan(batches[0], engine="sharded")
     if plan.engine.n_devices != n_devices:
         raise AssertionError(f"sharded over {plan.engine.n_devices} devices")
+    path = kernel_body(store, plan, sizes)
+    if path != ("gemm" if "classes" in sizes else "walk"):
+        raise AssertionError(f"sharded: the kernel ran its {path} body")
     single = ForestServer(store, n_devices=1, interpret=interpret)
     one_chip, one_times = serve_batches(single, batches, name="one chip")
     refs = user_references(store, fleet, batches)
@@ -321,8 +343,10 @@ def phase_sharded(seed: int, n_devices: int, sizes: dict = FLEET,
                 tol_scale=2.0)
     assert_served_on(sharded, "sharded")
     assert_served_on(single, "pipelined")
-    out = {"phase": "sharded", "devices": n_devices, "users": len(fleet),
-           "build_s": build_s, "batches": times, "one_chip": one_times}
+    out = {"phase": "sharded", "task": fleet_task(fleet),
+           "path": path, "devices": n_devices, "users": len(fleet),
+           "build_s": build_s,
+           "batches": times, "one_chip": one_times}
     out.update(server_report(sharded))
     out["peak_bytes_in_use"] = peak_bytes()
     return out
@@ -352,7 +376,8 @@ def main() -> None:
     )
     label = f"one smoke run on {dev.device_kind}, not a benchmark"
     if args.chips == 4:
-        phases = [lambda: phase_sharded(args.seed, 4)]
+        phases = [lambda: phase_sharded(args.seed, 4),
+                  lambda: phase_sharded(args.seed, 4, CLASS_FLEET)]
     else:
         phases = [lambda: phase_single(args.seed),
                   lambda: phase_fleet(args.seed)]

@@ -117,7 +117,7 @@ def shard_mesh(n_devices: int):
 
 def _sharded_program(
     mesh, max_depth: int, n_classes: int, block_trees: int,
-    block_obs: int, tb2: int, interpret: bool,
+    block_obs: int, tb2: int, interpret: bool, path: str,
 ):
     """The shard_map program over ``mesh``: each device runs the pipelined
     segmented kernel on ITS tree shard against the full replicated batch,
@@ -130,6 +130,7 @@ def _sharded_program(
         part = _forest_predict_agg_seg_pipelined_impl(
             xb, oseg, code[0], fit[0], tseg[0], chunk_lo[0], chunk_hi[0],
             max_depth, n_classes, block_trees, block_obs, tb2, interpret,
+            path,
         )
         if n_classes == 0:
             part = part[:, None]
@@ -180,7 +181,12 @@ def forest_predict_agg_segmented_sharded(
     Vote counts stay integer-exact under the reduction (float32 holds
     integers exactly below 2**24), so classification results are bit-exact
     against the single-device engines."""
-    from .tree_predict import _F32_EXACT_INT, _int32, _validate_f32_exact
+    from .tree_predict import (
+        _F32_EXACT_INT,
+        _int32,
+        _validate_f32_exact,
+        select_path,
+    )
 
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
@@ -196,15 +202,17 @@ def forest_predict_agg_segmented_sharded(
     arrays = {"xb": xb} if isinstance(xb, np.ndarray) else {}
     with span("serve.prep"):
         _validate_f32_exact(max_depth, d, **arrays)
+    block_obs = min(block_obs, n)
+    path = select_path(max_depth, n_classes, tb2, d, block_trees, block_obs)
     fn = _sharded_callable(
-        s, max_depth, n_classes, block_trees, min(block_obs, n), int(tb2),
-        interpret,
+        s, max_depth, n_classes, block_trees, block_obs, int(tb2),
+        interpret, path,
     )
     with span("tree_predict.upload"):
         args = jax.device_put([
             _int32(xb), _int32(obs_seg), code, fit, _int32(tree_seg),
             _int32(chunk_lo), _int32(chunk_hi),
         ])
-    with span("tree_predict.launch"):
+    with span("tree_predict.launch", path=path):
         out = fn(*args)
     return out[:, 0] if n_classes == 0 else out

@@ -57,11 +57,29 @@ The segmented kernel comes in TWO engines (``engine=`` on the wrapper):
     T_total * N_total, in ONE launch with no host round-trips between
     chunks.
 
+  Each streamed chunk is evaluated by one of TWO bodies, chosen from
+  static shapes by ``select_path``:
+
+  - ``"walk"``: ``max_depth`` levels of two-level gathers of the fused
+    code word plus a lane select of the row's feature, then the leaf fit
+    gather and the vote / fit sum (``_walk_votes``).  Regression, trees
+    deeper than 8 and wide feature sets take it.
+  - ``"gemm"`` (Hummingbird's GEMM strategy, for shallow classification
+    forests): per tree three MXU contractions — rows @ feature one-hot
+    gives every heap slot's decision bit, bits @ a constant path matrix
+    finds the bottom slot each row reaches, and the class one-hot of the
+    bottom slots against those hits counts the votes (``_gemm_votes``).
+    Every operand is an integer of at most 256, exact in int8 (bins up to
+    64) or bfloat16, and every sum is exact in int32 or float32.  The
+    bottom slots' classes are derived from the code and fit tables in the
+    jitted wrapper (``_gemm_tables``).
+
 Precision guard: node attributes round-trip through float32 gathers (the
 one-hot contraction runs at HIGHEST precision), which are exact only
 below 2**24 — ``forest_predict*`` validate static shapes and (when inputs
 are concrete) data ranges and raise instead of silently corrupting (see
-tests/test_serve_path.py boundary test).
+tests/test_serve_path.py boundary test).  The ``gemm`` body rounds
+nothing: it never moves a code word through a contraction.
 """
 from __future__ import annotations
 
@@ -423,7 +441,7 @@ def _forest_predict_agg_segmented_simple(
             _int32(tree_seg).reshape(t, 1), feature, threshold, fit,
             is_internal,
         ])
-    with span("tree_predict.launch"):
+    with span("tree_predict.launch", path="walk"):
         return _forest_predict_agg_seg_impl(
             *args, max_depth, n_classes, min(block_trees, t),
             min(block_obs, n), interpret,
@@ -497,26 +515,140 @@ def segment_chunk_ranges(
     return lo, hi
 
 
-def _tree_predict_agg_seg_pipelined_kernel(
-    chunk_lo_ref, chunk_hi_ref,  # SMEM (G,) int32 fori_loop bounds
-    tseg_ref,  # SMEM (T_pad,) int32 segment id per tree
-    xb_ref, oseg_ref,  # VMEM blocks
-    code_hbm, fit_hbm,  # ANY/HBM, DMA'd per chunk
-    out_ref,
-    *, max_depth: int, n_hi: int, n_classes: int, block_trees: int,
-    tb2: float,
-):
-    i = pl.program_id(0)
-    lo = chunk_lo_ref[i]
-    hi = chunk_hi_ref[i]
-    bn = xb_ref.shape[0]
-    c_out = out_ref.shape[0]
-    xb = xb_ref[...]
-    osegs = oseg_ref[...]  # (1, BN)
+#: the ``gemm`` body's limits: a path matrix at most 256 wide, at most
+#: 128 classes, and bins that clamp to ``TB`` exactly in bfloat16
+GEMM_MAX_DEPTH = 8
+GEMM_MAX_CLASSES = 128
+GEMM_MAX_TB = 256
+#: VMEM the ``gemm`` body's blocks and a chunk's temporaries may take
+#: (half of v5e's default scoped limit); wider feature sets walk
+GEMM_VMEM_BUDGET = 8 << 20
+
+
+def _round_up(a: int, m: int) -> int:
+    return -(-a // m) * m
+
+
+def _gemm_features(d: int) -> int:
+    """Feature axis of the ``gemm`` body's row block: int8's sublane
+    tile."""
+    return _round_up(d, 32)
+
+
+def _gemm_operand(tb2: int):
+    """The ``gemm`` body's operand type: int8 while rows clamped to ``TB``
+    fit it (the MXU's int8 rate is twice its bfloat16 rate), else
+    bfloat16; both hold every operand exactly."""
+    return jnp.int8 if tb2 // 2 <= 64 else jnp.bfloat16
+
+
+def _gemm_accumulator(dt):
+    return jnp.int32 if dt == jnp.int8 else jnp.float32
+
+
+def _gemm_width(max_depth: int) -> int:
+    """Lanes of the ``gemm`` body's slot axis: ``2**max_depth`` bottom
+    slots (and the internal slots above them), at least one lane row."""
+    return max(1 << max_depth, N_LO)
+
+
+def _gemm_vmem_bytes(block_trees, block_obs, d_pad, width) -> int:
+    """Bytes the ``gemm`` body holds at once, at bfloat16 operands: every
+    tree's temporaries of a chunk are live together."""
+    rows = 2 * block_obs * d_pad * 2  # row block, double-buffered
+    tables = 2 * 2 * block_trees * width * 4  # code and class slots
+    consts = width * width * 2 + width * 4  # path matrix and left turns
+    one_hot = block_trees * d_pad * width * (4 + 2)  # compare, operand
+    step = block_trees * block_obs * width * (4 + 2) * 2  # select, turns
+    return rows + tables + consts + one_hot + step
+
+
+def select_path(
+    max_depth: int, n_classes: int, tb2: int, d: int, block_trees: int,
+    block_obs: int,
+) -> str:
+    """The pipelined kernel's traversal body for these static shapes:
+    ``"gemm"`` (three MXU contractions per tree chunk) for shallow
+    classification forests, else ``"walk"`` (``max_depth`` levels of heap
+    gathers).  Regression walks: MXU summation would reorder its float32
+    sums."""
+    if not (
+        1 <= n_classes <= GEMM_MAX_CLASSES
+        and max_depth <= GEMM_MAX_DEPTH
+        and tb2 // 2 <= GEMM_MAX_TB
+    ):
+        return "walk"
+    need = _gemm_vmem_bytes(
+        block_trees, block_obs, _gemm_features(d), _gemm_width(max_depth)
+    )
+    return "gemm" if need <= GEMM_VMEM_BUDGET else "walk"
+
+
+@functools.lru_cache(maxsize=None)
+def _path_tables(max_depth: int) -> tuple[np.ndarray, np.ndarray]:
+    """The ``gemm`` body's constants over ``W = _gemm_width(max_depth)``
+    slots: ``C`` (W, W) with ``C[i, s]`` = +1 if bottom slot ``s`` lies in
+    the left subtree of heap slot ``i``, -1 if in the right, else 0; and
+    ``D`` (1, W), the left turns on the way to ``s`` (-1 past the bottom
+    level, which no row reaches).  A row's decision bits ``b`` reach
+    exactly the ``s`` with ``(b @ C)[s] == D[s]``."""
+    w = _gemm_width(max_depth)
+    n_bot = 1 << max_depth
+    c = np.zeros((w, w), np.float32)
+    lefts = np.full((1, w), -1.0, np.float32)
+    for s in range(n_bot):
+        node, turns = n_bot - 1 + s, 0
+        while node:
+            parent = (node - 1) // 2
+            left = node == 2 * parent + 1
+            c[parent, s] = 1.0 if left else -1.0
+            turns += left
+            node = parent
+        lefts[0, s] = turns
+    return c, lefts
+
+
+@functools.lru_cache(maxsize=None)
+def _bottom_ancestors(max_depth: int) -> np.ndarray:
+    """(max_depth + 1, 2**max_depth) heap slot of each bottom slot's
+    ancestor at each level, root first, the bottom slot itself last."""
+    node = np.arange(1 << max_depth) + (1 << max_depth)  # 1-based heap
+    return np.stack(
+        [(node >> (max_depth - k)) - 1 for k in range(max_depth + 1)]
+    ).astype(np.int32)
+
+
+def _gemm_tables(code, fit, max_depth, width):
+    """(T, W) slot tables for the ``gemm`` body, in XLA on the device:
+    the fused code words of heap slots 0..W-1, and per bottom slot the
+    class its rows vote, the fit of its first non-internal ancestor or
+    itself (the node a ``max_depth``-level walk stops at).  One gather of
+    each table along the bottom slots' paths, so the call adds a few
+    device ops, not a few per level."""
+    n_bot = 1 << max_depth
+    code = _pad_heap(code, max(code.shape[1], 2 * n_bot - 1, width))
+    fit = _pad_heap(fit, max(fit.shape[1], 2 * n_bot - 1))
+    anc = _bottom_ancestors(max_depth)
+    level = np.arange(max_depth + 1).reshape(-1, 1)
+    # a walk stops at its first leaf, or at the bottom after max_depth
+    stop = ((code[:, anc].astype(jnp.int32) & 1) == 0) | (level == max_depth)
+    first = jnp.where(stop, level, max_depth).min(axis=1, keepdims=True)
+    # the walk's class is its leaf fit truncated to int32
+    cls = jnp.where(level == first, fit[:, anc], 0.0).sum(1).astype(jnp.int32)
+    # no row reaches a lane past the bottom level (left-turn count -1)
+    return code[:, :width], _pad_heap(cls, width).astype(jnp.float32)
+
+
+def _walk_votes(xb, osegs, tseg_ref, *, max_depth, n_hi, n_classes,
+                block_trees, tb2):
+    """The ``walk`` body: per chunk, ``max_depth`` levels of two-level
+    heap gathers of the fused code word, a leaf gather of the fit, and the
+    segment-masked vote count."""
+    bn = xb.shape[0]
     tree_row = jax.lax.broadcasted_iota(jnp.int32, (block_trees, bn), 0)
 
-    def node_at(code3):
-        def fields(idx):
+    def fields(code3):
+        def node_at(idx):
             c = _two_level_gather(code3, idx)
             # power-of-two field scales: the reciprocal is exact, and so
             # is the multiply/floor decode
@@ -525,13 +657,129 @@ def _tree_predict_agg_seg_pipelined_kernel(
             th = jnp.floor(rem * 0.5)
             return fe.astype(jnp.int32), th, rem - 2.0 * th > 0.5
 
-        return fields
+        return node_at
 
-    def body(code_s, fit_s, sems):
+    def chunk_votes(ci, tables):
+        # the chunk's segment ids: scalar SMEM reads broadcast into rows
+        # (a (BT, 1) int32 DMA slice is not lane-aligned), before the
+        # wait for the chunk's DMAs
+        tseg = jnp.full((block_trees, bn), -1, jnp.int32)
+        for t in range(block_trees):
+            tseg = jnp.where(
+                tree_row == t, tseg_ref[ci * block_trees + t], tseg
+            )
+        code, fit = tables()
+        code3 = code.reshape(block_trees, n_hi, N_LO)
+        idx = _traverse(
+            xb, fields(code3), max_depth=max_depth, bt=block_trees
+        )
+        leaf = _two_level_gather(
+            fit.reshape(block_trees, n_hi, N_LO), idx
+        )  # (BT, BN)
+        # padding trees carry segment -1, which never matches a row
+        return _aggregate(leaf, tseg == osegs, n_classes)
+
+    return chunk_votes
+
+
+def _gemm_votes(xb, osegs, tseg_ref, turn_ref, lefts_ref, *, n_classes,
+                block_trees, tb2, n_features):
+    """The ``gemm`` body: per tree of a chunk, three contractions of
+    small-integer operands, exact in the row block's type (int8 or
+    bfloat16, ``_gemm_operand``) with int32 or float32 sums.
+
+    1. feature select: rows (BN, d_pad) @ one-hot of each slot's feature
+       (d_pad, W) gives every slot's feature value for every row, and
+       ``x <= thr`` the decision bits of every slot, leaf or padding too
+       (a row below a leaf still lands under that leaf);
+    2. path match: bits @ ``C`` equals ``D`` at exactly the bottom slot
+       the row reaches (``_path_tables``);
+    3. vote: the one-hot of each bottom slot's class (C_pad, W) against
+       the hits (BN, W), contracted over W, gives (C_pad, BN) votes."""
+    d_pad = xb.shape[1]
+    dt = xb.dtype
+    acc = _gemm_accumulator(dt)
+    c_pad = _round_up(n_classes, 32 if dt == jnp.int8 else 16)  # sublanes
+    turn = turn_ref[...]
+    lefts = lefts_ref[...].astype(acc)
+
+    def chunk_votes(ci, tables):
+        code, cls = tables()
+        width = code.shape[1]
+        # the walk's decode; feature ids clipped into [0, d) as it reads
+        fe = jnp.floor(code * (1.0 / tb2))
+        th = jnp.floor((code - fe * tb2) * 0.5).astype(acc)
+        fe = jnp.clip(fe, 0.0, float(n_features - 1)).astype(jnp.int32)
+        cls = cls.astype(jnp.int32)
+        feat_of = jax.lax.broadcasted_iota(jnp.int32, (d_pad, width), 0)
+        class_of = jax.lax.broadcasted_iota(jnp.int32, (c_pad, width), 0)
+        bn = xb.shape[0]
+        trees = range(block_trees)
+        # each contraction for every tree before the next one's: the
+        # trees' contractions then overlap on the MXU, where three
+        # dependent ones per tree wait on each other (2.8x slower, v5e)
+        bits = [
+            (jnp.dot(xb, (feat_of == fe[t:t + 1]).astype(dt),
+                     preferred_element_type=acc) <= th[t:t + 1]).astype(dt)
+            for t in trees
+        ]
+        hits = [
+            (jnp.dot(b, turn, preferred_element_type=acc) == lefts).astype(dt)
+            for b in bits
+        ]
+        votes = jnp.zeros((c_pad, bn), acc)
+        for t in trees:
+            voted = class_of == cls[t:t + 1]
+            if bn == 1:  # Mosaic cannot lower a one-column dot
+                v = jnp.where(voted, hits[t].astype(acc), 0).sum(
+                    1, keepdims=True
+                )
+            else:
+                v = jax.lax.dot_general(
+                    voted.astype(dt), hits[t], (((1,), (1,)), ((), ())),
+                    preferred_element_type=acc,
+                )
+            # padding trees carry segment -1, which never matches a row
+            same = osegs == tseg_ref[ci * block_trees + t]
+            votes = votes + jnp.where(same, v, 0)
+        return votes[:n_classes].astype(jnp.float32)
+
+    return chunk_votes
+
+
+def _tree_predict_agg_seg_pipelined_kernel(
+    chunk_lo_ref, chunk_hi_ref,  # SMEM (G,) int32 fori_loop bounds
+    tseg_ref,  # SMEM (T_pad,) int32 segment id per tree
+    xb_ref, oseg_ref,  # VMEM blocks
+    *refs,  # gemm: path matrix, left turns (VMEM); then the two
+    # per-tree tables in ANY/HBM, DMA'd per chunk; then the output
+    path: str, max_depth: int, n_hi: int, n_classes: int, block_trees: int,
+    tb2: float, n_features: int,
+):
+    *consts, code_hbm, tab_hbm, out_ref = refs
+    i = pl.program_id(0)
+    lo = chunk_lo_ref[i]
+    hi = chunk_hi_ref[i]
+    bn = xb_ref.shape[0]
+    width = code_hbm.shape[1]
+    xb = xb_ref[...]
+    osegs = oseg_ref[...]  # (1, BN)
+    if path == "gemm":
+        chunk_votes = _gemm_votes(
+            xb, osegs, tseg_ref, *consts, n_classes=n_classes,
+            block_trees=block_trees, tb2=tb2, n_features=n_features,
+        )
+    else:
+        chunk_votes = _walk_votes(
+            xb, osegs, tseg_ref, max_depth=max_depth, n_hi=n_hi,
+            n_classes=n_classes, block_trees=block_trees, tb2=tb2,
+        )
+
+    def body(code_s, tab_s, sems):
         # one DMA pair per (slot, chunk); fresh descriptors are cheap —
         # start() and wait() pair up through the per-(slot, k) semaphore
         def dma(slot, ci, k):
-            src, dst = ((code_hbm, code_s), (fit_hbm, fit_s))[k]
+            src, dst = ((code_hbm, code_s), (tab_hbm, tab_s))[k]
             return pltpu.make_async_copy(
                 src.at[pl.ds(ci * block_trees, block_trees)],
                 dst.at[slot],
@@ -552,32 +800,22 @@ def _tree_predict_agg_seg_pipelined_kernel(
                 for k in range(2):
                     dma((step + 1) % 2, ci + 1, k).start()
 
-            # the chunk's segment ids: scalar SMEM reads broadcast into
-            # rows (a (BT, 1) int32 DMA slice is not lane-aligned)
-            tseg = jnp.full((block_trees, bn), -1, jnp.int32)
-            for t in range(block_trees):
-                tseg = jnp.where(
-                    tree_row == t, tseg_ref[ci * block_trees + t], tseg
-                )
-            for k in range(2):
-                dma(cur, ci, k).wait()
-            code3 = code_s[cur].reshape(block_trees, n_hi, N_LO)
-            idx = _traverse(
-                xb, node_at(code3), max_depth=max_depth, bt=block_trees
-            )
-            fit3 = fit_s[cur].reshape(block_trees, n_hi, N_LO)
-            leaf = _two_level_gather(fit3, idx)  # (BT, BN)
-            # padding trees carry segment -1, which never matches a row
-            return acc + _aggregate(leaf, tseg == osegs, n_classes)
+            def tables():  # the chunk's two tables, once they landed
+                for k in range(2):
+                    dma(cur, ci, k).wait()
+                return code_s[cur], tab_s[cur]
+
+            return acc + chunk_votes(ci, tables)
 
         out_ref[...] = jax.lax.fori_loop(
-            0, hi - lo, chunk_step, jnp.zeros((c_out, bn), jnp.float32)
+            0, hi - lo, chunk_step,
+            jnp.zeros((out_ref.shape[0], bn), jnp.float32),
         )
 
     pl.run_scoped(
         body,
-        pltpu.VMEM((2, block_trees, n_hi * N_LO), jnp.float32),
-        pltpu.VMEM((2, block_trees, n_hi * N_LO), jnp.float32),
+        pltpu.VMEM((2, block_trees, width), jnp.float32),
+        pltpu.VMEM((2, block_trees, width), jnp.float32),
         pltpu.SemaphoreType.DMA((2, 2)),
     )
 
@@ -586,35 +824,53 @@ def _tree_predict_agg_seg_pipelined_kernel(
     jax.jit,
     static_argnames=(
         "max_depth", "n_classes", "block_trees", "block_obs", "tb2",
-        "interpret",
+        "interpret", "path",
     ),
 )
 def _forest_predict_agg_seg_pipelined_impl(
     xb, obs_seg, code, fit, tree_seg, chunk_lo, chunk_hi,
-    max_depth, n_classes, block_trees, block_obs, tb2, interpret,
+    max_depth, n_classes, block_trees, block_obs, tb2, interpret, path,
 ):
+    """``path`` is the traversal body, ``select_path``'s answer for these
+    shapes (tests force either)."""
     t_pad, h = code.shape
     n, d = xb.shape
     n_hi = _heap_split(h)
-    h_pad = n_hi * N_LO
-    code = _pad_heap(code, h_pad)
-    fit = _pad_heap(fit, h_pad)
     c_out = n_classes if n_classes > 0 else 1
-    grid = (pl.cdiv(n, block_obs),)
+    consts, const_specs = [], []
+    if path == "gemm":
+        # rows clamp to TB: every x <= thr (thr < TB) keeps its answer,
+        # and every value is an integer of at most TB, exact in ``dt``
+        dt = _gemm_operand(tb2)
+        d_pad = _gemm_features(d)
+        xb = jnp.pad(
+            jnp.clip(xb, -1, tb2 // 2).astype(dt), ((0, 0), (0, d_pad - d))
+        )
+        code, tab = _gemm_tables(code, fit, max_depth, _gemm_width(max_depth))
+        turn, lefts = _path_tables(max_depth)
+        consts = [jnp.asarray(turn, dt), jnp.asarray(lefts)]
+        const_specs = [
+            pl.BlockSpec(a.shape, lambda i: (0, 0)) for a in consts
+        ]
+    else:
+        h_pad = n_hi * N_LO
+        code = _pad_heap(code, h_pad)
+        tab = _pad_heap(fit, h_pad)
     kernel = functools.partial(
         _tree_predict_agg_seg_pipelined_kernel,
-        max_depth=max_depth, n_hi=n_hi, n_classes=n_classes,
-        block_trees=block_trees, tb2=float(tb2),
+        path=path, max_depth=max_depth, n_hi=n_hi, n_classes=n_classes,
+        block_trees=block_trees, tb2=float(tb2), n_features=d,
     )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(pl.cdiv(n, block_obs),),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_obs, d), lambda i: (i, 0)),
+            pl.BlockSpec((block_obs, xb.shape[1]), lambda i: (i, 0)),
             pl.BlockSpec((1, block_obs), lambda i: (0, i)),
+            *const_specs,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -622,7 +878,10 @@ def _forest_predict_agg_seg_pipelined_impl(
         out_shape=jax.ShapeDtypeStruct((c_out, n), jnp.float32),
         interpret=interpret,
         name="tree_predict_agg_seg_pipelined",
-    )(chunk_lo, chunk_hi, tree_seg, xb, obs_seg.reshape(1, n), code, fit)
+    )(
+        chunk_lo, chunk_hi, tree_seg, xb, obs_seg.reshape(1, n), *consts,
+        code, tab,
+    )
     return out[0] if n_classes == 0 else out.T
 
 
@@ -671,11 +930,13 @@ def forest_predict_agg_segmented_packed(
         xb, obs_seg, tree_seg, chunk_lo, chunk_hi = jax.device_put([
             _int32(a) for a in (xb, obs_seg, tree_seg, chunk_lo, chunk_hi)
         ])
-    with span("tree_predict.launch"):
+    block_obs = min(block_obs, n)
+    path = select_path(max_depth, n_classes, tb2, d, block_trees, block_obs)
+    with span("tree_predict.launch", path=path):
         return _forest_predict_agg_seg_pipelined_impl(
             xb, obs_seg, code, fit, tree_seg, chunk_lo, chunk_hi,
-            max_depth, n_classes, block_trees, min(block_obs, n), int(tb2),
-            interpret,
+            max_depth, n_classes, block_trees, block_obs, int(tb2),
+            interpret, path,
         )
 
 

@@ -29,7 +29,9 @@ Span                  Covers
                       the rows, segment ids and chunk ranges (and tree
                       tiles on the ``simple`` engine).
 ``tree_predict.launch`` the jitted kernel call until it returns: dispatch
-                      only, the kernel runs on after it.
+                      only, the kernel runs on after it.  Arg:
+                      ``path`` (the traversal body, ``gemm`` or
+                      ``walk``).
 ``serve.wait``        each engine's copy of the answer back to the host,
                       which first waits for the device.
 ``serve.finalize``    each engine's un-permute, and

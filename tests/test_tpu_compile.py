@@ -12,6 +12,7 @@ from repro.kernels.tree_predict.ops import _sharded_program
 from repro.kernels.tree_predict.tree_predict import (
     _forest_predict_agg_seg_impl,
     _forest_predict_agg_seg_pipelined_impl,
+    select_path,
 )
 from repro.serving.plan import ENGINE_BLOCKS
 
@@ -64,14 +65,23 @@ def _assert_kernel(compiled) -> str:
         (10, 55, 7, 504, 4096),  # the smoke's single forest
         (10, 55, 7, 504, 1),  # ... scoring one row
         (8, 32, 0, 1280, 8192),  # the smoke's regression fleet batch
+        (8, 54, 7, 504, 4096),  # the benchmark's forest: the gemm body
+        (8, 54, 7, 504, 1),  # ... scoring one row
     ],
 )
 def test_pipelined_segmented_compiles(one_chip, depth, d, n_classes,
                                       t_pad, n):
+    _compile_pipelined(one_chip, depth, d, n_classes, t_pad, n, TB2)
+
+
+def _compile_pipelined(one_chip, depth, d, n_classes, t_pad, n, tb2):
+    """Compile the pipelined kernel at the engine's blocks, with the body
+    ``select_path`` picks for these shapes; returns that body."""
     bt, bo = ENGINE_BLOCKS["pipelined"]
     bo = min(bo, n)
     h = (1 << (depth + 1)) - 1
     g = -(-n // bo)
+    path = select_path(depth, n_classes, tb2, d, bt, bo)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -80,9 +90,31 @@ def test_pipelined_segmented_compiles(one_chip, depth, d, n_classes,
         s((n, d), jnp.int32), s((n,), jnp.int32),
         s((t_pad, h), jnp.float32), s((t_pad, h), jnp.float32),
         s((t_pad,), jnp.int32), s((g,), jnp.int32), s((g,), jnp.int32),
-        depth, n_classes, bt, bo, TB2, False,
+        depth, n_classes, bt, bo, tb2, False, path,
     ).compile()
     _assert_kernel(compiled)
+    return path
+
+
+@pytest.mark.parametrize(
+    "depth,d,n_classes,n,tb2",
+    [
+        (8, 384, 7, 4096, 64),  # the widest features depth 8 admits
+        (8, 384, 7, 4096, 512),  # ... with bfloat16 operands (255 bins)
+        (8, 54, 7, 4096, 512),  # the benchmark's shapes, bfloat16
+        (8, 54, 7, 1, 512),  # ... scoring one row
+        (8, 54, 7, 37, 64),  # a row block narrower than a lane row
+        (8, 54, 7, 37, 512),  # ... bfloat16
+        (7, 992, 7, 4096, 64),  # the widest features depth 7 admits
+        (3, 1, 2, 4096, 64),  # one feature, eight bottom slots
+    ],
+)
+def test_gemm_body_compiles_at_the_rule_edges(one_chip, depth, d,
+                                              n_classes, n, tb2):
+    """Every shape ``select_path`` sends to ``gemm`` must compile: at the
+    VMEM budget's edge, with either operand type, and at ragged rows."""
+    path = _compile_pipelined(one_chip, depth, d, n_classes, 504, n, tb2)
+    assert path == "gemm"
 
 
 def test_simple_segmented_compiles_at_engine_blocks(one_chip):
@@ -103,12 +135,23 @@ def test_simple_segmented_compiles_at_engine_blocks(one_chip):
 
 
 def test_sharded_program_compiles_on_2x2(topo):
+    # a regression fleet: the walk body
+    _compile_sharded(topo, 8, 32, 0, 8192, 320, "walk")
+
+
+def test_sharded_gemm_program_compiles_on_2x2(topo):
+    # the benchmark's classification forest over four chips: the gemm body
+    _compile_sharded(topo, 8, 54, 7, 4096, 128, "gemm")
+
+
+def _compile_sharded(topo, depth, d, n_classes, n, t_pad, want):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     mesh = Mesh(np.array(topo.devices), ("shard",))
     n_dev = mesh.devices.size
     bt, bo = ENGINE_BLOCKS["sharded"]
-    depth, d, n, t_pad = 8, 32, 8192, 320
+    path = select_path(depth, n_classes, TB2, d, bt, bo)
+    assert path == want
     h = (1 << (depth + 1)) - 1
     g = n // bo
     rep, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P("shard"))
@@ -121,6 +164,8 @@ def test_sharded_program_compiles_on_2x2(topo):
         jax.ShapeDtypeStruct((n_dev, g), jnp.int32, sharding=shard),
         jax.ShapeDtypeStruct((n_dev, g), jnp.int32, sharding=shard),
     ]
-    program = _sharded_program(mesh, depth, 0, bt, bo, TB2, False)
+    program = _sharded_program(
+        mesh, depth, n_classes, bt, bo, TB2, False, path
+    )
     text = _assert_kernel(jax.jit(program).lower(*args).compile())
     assert "all-reduce" in text
