@@ -13,9 +13,9 @@ number it gives as they are:
 * ``span_table`` gives, per name, the count, the summed duration and the
   summed self time of the spans inside the window.  A span's self time is
   its duration less the union of the spans nested in it on its line;
-* ``idle_by_span`` puts each idle gap of the device down to the innermost
-  span that covers its middle, ``devtrace.summarize``'s rule, by a sweep
-  that stays fast with hundreds of thousands of spans;
+* ``device_gaps`` and ``idle_by_span`` are ``devtrace``'s: the device's
+  idle gaps, each put down to the innermost span that covers its middle,
+  by a sweep that stays fast with hundreds of thousands of spans;
 * ``idle_in_spans`` splits the idle time across the stages instead: each
   name gets the idle time inside its self intervals.
 
@@ -33,7 +33,6 @@ It checks no answer against the reference; ``bench/run.py`` does that.
 from __future__ import annotations
 
 import bisect
-import heapq
 import sys
 from collections import defaultdict
 from dataclasses import dataclass
@@ -139,62 +138,17 @@ def per_call_ms(table: dict, name: str) -> float | None:
     return 1e3 * table[name][2] / calls
 
 
-def device_gaps(devices: dict, lo: float, hi: float) -> list[tuple[float, float]]:
-    """Intervals of ``[lo, hi]`` in which no chip ran an operation."""
-    merged = []
-    for events in devices.values():
-        inside = [(e.start_ns, e.end_ns) for e in events
-                  if e.end_ns > lo and e.start_ns < hi]
-        merged.extend(devtrace._union(devtrace._clip(inside, lo, hi)))
-    gaps, t = [], lo
-    for s, e in devtrace._union(merged) + [(hi, hi)]:
-        if s > t:
-            gaps.append((t, s))
-        t = max(t, e)
-    return gaps
-
-
-def idle_by_span(gaps, spans: list[devtrace.Event]) -> dict[str, float]:
-    """Seconds of the gaps put down to the innermost (shortest) span that
-    covers each gap's middle, ``bench.window`` where none does; ties go to
-    the span listed first, as in ``devtrace.summarize``."""
-    order = sorted((s for s in enumerate(spans)
-                    if s[1].name != "bench.window"),
-                   key=lambda s: s[1].start_ns)
-    heap: list[tuple[float, int, float, str]] = []
-    out: dict[str, float] = defaultdict(float)
-    k = 0
-    for s, e in sorted(gaps):
-        mid = (s + e) / 2
-        while k < len(order) and order[k][1].start_ns <= mid:
-            i, h = order[k]
-            heapq.heappush(heap, (h.dur_ns, i, h.end_ns, h.name))
-            k += 1
-        while heap and heap[0][2] <= mid:
-            heapq.heappop(heap)
-        out[heap[0][3] if heap else "bench.window"] += e - s
-    return {name: v / 1e9 for name, v in out.items()}
+#: the device's idle intervals and their labels, as ``devtrace.summarize``
+#: finds them
+device_gaps = devtrace.device_gaps
+idle_by_span = devtrace.idle_by_span
 
 
 def idle_in_spans(gaps, spans: list[Span]) -> dict[str, float]:
     """Seconds of the gaps that fall in each name's self intervals: the
     device's idle time split across the stages it overlaps.  On one line
     the parts add up to the gaps; a gap counts once on each line."""
-    gaps = sorted(gaps)
-    starts = [s for s, _ in gaps]
-    ends = [e for _, e in gaps]
-    cum = [0.0]
-    for s, e in gaps:
-        cum.append(cum[-1] + e - s)
-
-    def idle(lo, hi):
-        j0 = bisect.bisect_right(ends, lo)
-        j1 = bisect.bisect_left(starts, hi)
-        if j1 <= j0:
-            return 0.0
-        return (cum[j1] - cum[j0] - max(0.0, lo - starts[j0])
-                - max(0.0, ends[j1 - 1] - hi))
-
+    idle = devtrace.overlap_with(sorted(gaps))
     out: dict[str, float] = defaultdict(float)
     for s, iv in zip(spans, self_intervals(spans)):
         for lo, hi in iv:
